@@ -10,12 +10,15 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 MODULES = ["fig9_endurance", "table4_offload", "fig10_overhead",
            "fig11_rok", "io_backends", "spool_datapath",
            "cache_manager", "roofline"]
 
 
 def main() -> None:
+    enable_compile_cache()
     want = sys.argv[1:] or MODULES
     for name in want:
         mod = name if name in MODULES else next(

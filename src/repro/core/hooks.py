@@ -70,6 +70,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
@@ -78,7 +79,7 @@ from repro.core.hostcb import raw_io_callback as io_callback
 from repro.core.spool import ActivationSpool, SpoolStepTransaction
 from repro.parallel.shmap import (axes_size, canonical_axis_entry,
                                   linear_axis_index, local_shape,
-                                  mesh_size, shard_map, spec_axes)
+                                  mesh_size, spec_axes)
 
 #: stage-index offset for encoder-stream layers, so one step lease can
 #: hold both streams without key collisions (decoder layers are 0-based)

@@ -16,7 +16,7 @@ callback machinery — the same `_IOEffect` (so jit/scan treat it exactly
 like `io_callback`: not DCE'd, allowed in control flow, droppable only
 when result-free, which our token threading already prevents) and the
 same MANUAL op-sharding under shard_map (one callback per device) — but
-lowers through `mlir.emit_python_callback` directly, so the python
+lowers through `emit_python_callback` directly, so the python
 function receives the raw numpy VIEWS of the XLA operand buffers, no
 jax arrays, no device_put, no events. Nothing in the callback can touch
 the jax runtime, so nothing in the callback can deadlock it.
@@ -29,67 +29,60 @@ Contract (stricter than io_callback — the device_put was also a copy):
   * no vmap / differentiation through the primitive (the hooks never do
     either — it lives inside a custom_vjp's fwd/bwd).
 
-Falls back to `jax.experimental.io_callback` when the jax internals it
-borrows move (import errors are caught), trading the liveness fix for
-compatibility; `RAW_CALLBACK_AVAILABLE` says which one callers got.
+The borrowed internals (`jax._src.callback.emit_python_callback`,
+`_callback_op_sharding`, `_IOEffect`) are imported unguarded: if a jax
+upgrade moves them, importing this module fails loudly rather than
+falling back to a path that can deadlock a mesh.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Sequence, Tuple
+from typing import Any, Callable, Tuple
 
-import numpy as np
+from jax._src import core as _jcore
+from jax._src.callback import _callback_op_sharding, _IOEffect
+from jax._src.callback import emit_python_callback
+from jax._src.interpreters import mlir as _mlir
+from jax._src.sharding_impls import SPMDAxisContext
 
-import jax
+raw_callback_p = _jcore.Primitive("repro_raw_host_callback")
+raw_callback_p.multiple_results = True
 
-RAW_CALLBACK_AVAILABLE = False
 
-try:
-    import inspect
+@raw_callback_p.def_effectful_abstract_eval
+def _raw_callback_abstract_eval(*avals, callback, result_avals):
+    del avals, callback
+    return result_avals, {_IOEffect}
 
-    from jax._src import core as _jcore
-    from jax._src.callback import _callback_op_sharding as _op_sharding
-    from jax._src.callback import _IOEffect
-    from jax._src.interpreters import mlir as _mlir
 
-    # Guard against call-signature drift, not just import-time moves:
-    # both borrowed internals have changed shape across jax 0.4.x, and
-    # a mismatch would otherwise crash at lowering time instead of
-    # falling back. (A full smoke lower would need a jax backend, which
-    # module import must not initialize.)
-    _ep = list(inspect.signature(_mlir.emit_python_callback).parameters)
-    if _ep[:6] != ["ctx", "callback", "token", "operands",
-                   "operand_avals", "result_avals"] \
-            or "has_side_effect" not in _ep or "sharding" not in _ep:
-        raise ImportError("emit_python_callback signature drifted")
-    if len(inspect.signature(_op_sharding).parameters) != 2:
-        raise ImportError("_callback_op_sharding signature drifted")
+def _raw_callback_lowering(ctx, *args, callback, result_avals):
+    del result_avals
 
-    raw_callback_p = _jcore.Primitive("repro_raw_host_callback")
-    raw_callback_p.multiple_results = True
+    def _wrapped(*flat_args):
+        out = callback(*flat_args)
+        return tuple(out) if isinstance(out, (tuple, list)) else (out,)
 
-    @raw_callback_p.def_effectful_abstract_eval
-    def _raw_callback_abstract_eval(*avals, callback, result_avals):
-        del avals, callback
-        return result_avals, {_IOEffect}
+    axis_context = ctx.module_context.axis_context
+    op_sharding = _callback_op_sharding(axis_context, None, ctx.avals_out)
+    if ctx.module_context.platforms[0] == "tpu" and \
+            isinstance(axis_context, SPMDAxisContext):
+        # On TPU the callback is one send per operand and one recv per
+        # result, and jax puts the same per-result sharding list on
+        # every one of them, which the verifier rejects when the ranks
+        # differ. `_callback_op_sharding` above has already refused a
+        # body that leaves any mesh axis automatic, so this one is a
+        # fully manual shard_map body: each device's ops are local, and
+        # they are left unannotated.
+        op_sharding = None
+    result, _, _ = emit_python_callback(
+        ctx, _wrapped, None, list(args), ctx.avals_in, ctx.avals_out,
+        has_side_effect=True, returns_token=False, sharding=op_sharding)
+    return result
 
-    def _raw_callback_lowering(ctx, *args, callback, result_avals):
-        del result_avals
 
-        def _wrapped(*flat_args):
-            out = callback(*flat_args)
-            return (tuple(out) if isinstance(out, (tuple, list))
-                    else (out,))
-
-        op_sharding = _op_sharding(ctx.module_context.axis_context, None)
-        result, _, _ = _mlir.emit_python_callback(
-            ctx, _wrapped, None, list(args), ctx.avals_in, ctx.avals_out,
-            has_side_effect=True, sharding=op_sharding)
-        return result
-
-    _mlir.register_lowering(raw_callback_p, _raw_callback_lowering)
-    RAW_CALLBACK_AVAILABLE = True
-except Exception:  # pragma: no cover - future jax moved the internals
-    pass
+# Not cacheable, as for jax's own io_callback: on TPU each lowered
+# callback carries its own channel id.
+_mlir.register_lowering(raw_callback_p, _raw_callback_lowering,
+                        cacheable=False)
 
 
 def raw_io_callback(callback: Callable[..., Any], result_shape_dtypes,
@@ -114,9 +107,6 @@ def raw_io_callback(callback: Callable[..., Any], result_shape_dtypes,
         with obs.span(span_name, cat="hostcb"):
             return callback(*flat_args)
 
-    if not RAW_CALLBACK_AVAILABLE:  # pragma: no cover - fallback path
-        from jax.experimental import io_callback
-        return io_callback(traced_callback, result_shape_dtypes, *args)
     result_avals = tuple(
         _jcore.ShapedArray(tuple(s.shape), s.dtype) for s in sds)
     out = raw_callback_p.bind(*args, callback=traced_callback,

@@ -126,6 +126,9 @@ class SpoolStats:
     bytes_loaded: int = 0
     bytes_forwarded: int = 0
     bytes_deduped: int = 0
+    # records served from memory instead of the backend (tensor
+    # forwarding, or a failed store whose arrays were still held)
+    num_forwarded: int = 0
     stores_canceled: int = 0
     store_time: float = 0.0
     load_time: float = 0.0
@@ -623,6 +626,7 @@ class ActivationSpool:
                         # is one forwarding event, not two
                         rec["fwd_counted"] = True
                         self.stats.bytes_forwarded += _nbytes(spooled)
+                        self.stats.num_forwarded += 1
                     if job.state == QUEUED and cancel_pending:
                         job.state = CANCELED
                         self.stats.stores_canceled += 1
@@ -638,6 +642,7 @@ class ActivationSpool:
                         # forwarding, not two
                         rec["fwd_counted"] = True
                         self.stats.bytes_forwarded += _nbytes(spooled)
+                        self.stats.num_forwarded += 1
             if spooled is None:
                 with self._lock:
                     lj = rec["load_job"]

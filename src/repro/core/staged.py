@@ -53,6 +53,7 @@ from repro.core.spool import build_spool
 from repro.models.api import ModelApi
 from repro.models.layers import rms_norm
 from repro.models.transformer import RunSettings, apply_block
+from repro.optim.optimizers import global_norm
 
 
 def _nbytes(tree) -> int:
@@ -155,6 +156,36 @@ class StagedTrainer:
         self._profiles: Optional[List[ModuleProfile]] = None
         self._stages = self._build_stages()
         self._step = 0
+        # one jitted update that writes the new state over the old:
+        # without donation both copies of params and opt state are live
+        self._update = jax.jit(self._update_fn, static_argnums=(3,),
+                               donate_argnums=(1, 2))
+        self._device = jax.local_devices()[0]
+        #: times the forward drained the store queue for device room
+        self.device_room_waits = 0
+
+    def _make_device_room(self, need: int) -> None:
+        """Wait for the queued stores to land when the device cannot
+        take `need` more bytes. A store holds its residuals on the
+        device until its write lands, and the forward outruns the store
+        path on an accelerator: qwen2.5-3b layers at 4096 tokens emit
+        ~2.6 GB of residuals each in milliseconds, and their fs stores
+        take seconds, so four layers' worth would not fit a 16 GB chip
+        next to the training state. A device that reports no memory
+        limit (the CPU) never waits."""
+        stats = self._device.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return
+        if stats["bytes_in_use"] + need > stats["bytes_limit"]:
+            self.device_room_waits += 1
+            with obs.span("engine.wait_device_room", cat="engine",
+                          step=self._step):
+                self.spool.wait_io()
+
+    def _update_fn(self, grads, opt_state, params, scale: float):
+        grads = jax.tree.map(lambda g: g * scale, grads)
+        params, opt_state = self.optimizer.update(grads, opt_state, params)
+        return params, opt_state, global_norm(grads)
 
     @property
     def plan(self) -> Optional[OffloadPlan]:
@@ -171,7 +202,7 @@ class StagedTrainer:
         api, cfg, settings = self.api, self.cfg, self.settings
         stages: List[_Stage] = []
 
-        from repro.models.api import _embed_in, _head  # internal reuse
+        from repro.models.api import _embed_in, head_ce_terms
         import dataclasses as _dc
 
         # ---- encoder stream (T5) / stub frontend (VLM)
@@ -234,13 +265,9 @@ class StagedTrainer:
                                      "layer", takes_enc=takes_enc))
 
         def head_fn(p, x, labels):
-            logits = _head(p, x, cfg)
-            mask = (labels >= 0).astype(jnp.float32)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(
-                logits, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
-            return ((lse - picked) * mask).sum() / jnp.maximum(mask.sum(),
-                                                               1.0)
+            # chunked under settings.ce_chunk, as in the whole-step loss
+            nll, tokens = head_ce_terms(p, x, labels, cfg, settings)
+            return nll / jnp.maximum(tokens, 1.0)
         stages.append(_Stage("head", head_fn, "head"))
         return stages
 
@@ -313,12 +340,9 @@ class StagedTrainer:
 
             # ---------------- optimizer ----------------
             with obs.span("engine.update", cat="engine", step=self._step):
-                grads_tree = self._unstage_grads(grads)
-                scale = 1.0 / len(batches)
-                grads_tree = jax.tree.map(lambda g_: g_ * scale,
-                                          grads_tree)
-                params, opt_state = self.optimizer.update(
-                    grads_tree, opt_state, params)
+                params, opt_state, grad_norm = self._update(
+                    self._unstage_grads(grads), opt_state, params,
+                    1.0 / len(batches))
                 jax.block_until_ready(jax.tree.leaves(params)[0])
         # The store tail is NOT synchronised here: adaptive offloading
         # (§3.3.3) schedules writes to complete inside the backward pass,
@@ -349,7 +373,8 @@ class StagedTrainer:
             peak_activation_bytes=self.tracker.peak,
             backward_begin_bytes=bwd_begin_bytes,
             stats=self.spool.stats, plan=self.plan,
-            step=self._step, engine="staged")
+            step=self._step, engine="staged",
+            extra={"grad_norm": float(grad_norm)})
 
     def _run_microbatch(self, tx, mb, batch, stage_params, n_stages,
                         grads, loss_total, profiles, bwd_begin_bytes):
@@ -366,6 +391,9 @@ class StagedTrainer:
                           mb=mb)
         fwd_sp.__enter__()
         for si, stage in enumerate(self._stages):
+            # room for this stage's residuals and its working set, sized
+            # by the largest stage profiled so far in this step
+            self._make_device_room(2 * max(p.bytes for p in profiles))
             args = self._args_for(stage, batch, x, xe, enc)
             tin = time.perf_counter()
             if self.policy.recomputes(stage.role):
@@ -416,6 +444,9 @@ class StagedTrainer:
                     tx.keep(si, list(r_leaves.values()))
                 profiles[si] = profile
                 stage.cell.setdefault("resid_idx", tuple(r_leaves))
+                # the store job now holds the only reference: a landed
+                # store frees the device memory
+                del r_leaves
             del leaves
 
         fwd_sp.__exit__(None, None, None)
@@ -475,6 +506,9 @@ class StagedTrainer:
                         leaves[i] = l
                     outs = stage.bwd(tuple(leaves), carry_g)
                     jax.block_until_ready(outs[0])
+                    # free this stage's residuals before the next fetch
+                    # brings another stage's onto the device
+                    del leaves, r_list
                 tx.drop(si)
                 kept.pop(si)
                 fallback_in.pop(si, None)

@@ -43,7 +43,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.io.backend import (StorageBackend, as_memoryviews,
-                              pwritev_all, register_backend)
+                              blob_filename, pwritev_all, register_backend)
 from repro.io.bufpool import DEFAULT_ALIGNMENT, AlignedBufferPool
 
 
@@ -119,7 +119,7 @@ class AioBackend(StorageBackend):
     # ------------------------------------------------------------ paths
 
     def _path(self, key: str) -> str:
-        return os.path.join(self.directory, f"{key}.act")
+        return os.path.join(self.directory, blob_filename(key, ".act"))
 
     def _segments(self, nbytes: int) -> List[tuple]:
         """Split [0, nbytes) into up to queue_depth aligned spans."""

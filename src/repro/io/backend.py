@@ -37,6 +37,7 @@ import errno
 import os
 import threading
 import time
+import urllib.parse
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Type
 
@@ -154,6 +155,13 @@ class IoStats:
                 / (self.bytes_written + self.bytes_read)
                 if (self.bytes_written + self.bytes_read) else 0.0),
         }
+
+
+def blob_filename(key: str, suffix: str) -> str:
+    """File name of a blob key on a file-backed backend. Keys may hold
+    "/" (the per-shard leases of a mesh, `jit3/s1`), which a file name
+    cannot; percent-quoting keeps the mapping one-to-one."""
+    return urllib.parse.quote(key, safe="") + suffix
 
 
 def as_memoryviews(parts) -> List[memoryview]:

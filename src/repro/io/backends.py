@@ -12,8 +12,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from repro import obs
 from repro.cache.placement import PlacementEngine
 from repro.core.adaptive import TierBandwidth
-from repro.io.backend import (StorageBackend, as_memoryviews, preadv_all,
-                              pwritev_all, register_backend)
+from repro.io.backend import (StorageBackend, as_memoryviews,
+                              blob_filename, preadv_all, pwritev_all,
+                              register_backend)
 
 
 @register_backend("fs")
@@ -38,7 +39,7 @@ class FilesystemBackend(StorageBackend):
         os.makedirs(directory, exist_ok=True)
 
     def _path(self, key: str) -> str:
-        return os.path.join(self.directory, f"{key}.act")
+        return os.path.join(self.directory, blob_filename(key, ".act"))
 
     def _tmp_path(self, key: str) -> str:
         # pid+tid suffix: concurrent writers of *different* keys (the
@@ -154,7 +155,8 @@ class StripedBackend(StorageBackend):
         return (start + i) % len(self.directories)
 
     def _path_on(self, dev: int, key: str, i: int) -> str:
-        return os.path.join(self.directories[dev], f"{key}.c{i}")
+        return os.path.join(self.directories[dev],
+                            blob_filename(key, f".c{i}"))
 
     def _chunk_path(self, key: str, i: int) -> str:
         # default (pre-rebalance) placement; kept for back-compat

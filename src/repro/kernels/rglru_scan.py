@@ -3,7 +3,7 @@
     h_t = exp(log_a_t) * h_{t-1} + x_t
 
 Grid (B, W_blocks, n_chunks); the chunk axis is sequential with the hidden
-state h (blk_w,) f32 carried in VMEM scratch. Within a chunk the recurrence
+state h (1, blk_w) f32 carried in VMEM scratch. Within a chunk the recurrence
 runs as a fori_loop over time steps on the VPU — the recurrence is
 elementwise over the width dim, so each step is a (blk_w,)-wide FMA; the
 chunking exists to keep the working set in VMEM and to overlap the HBM
@@ -19,11 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rglru_kernel(a_ref, x_ref, y_ref, h_ref, *, chunk: int):
@@ -33,13 +29,14 @@ def _rglru_kernel(a_ref, x_ref, y_ref, h_ref, *, chunk: int):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0].astype(jnp.float32)                  # (chunk, blk_w)
-    x = x_ref[0].astype(jnp.float32)
-
-    def step(t, carry):
-        h = carry
-        h = jnp.exp(a[t]) * h + x[t]
-        y_ref[0, t] = h.astype(y_ref.dtype)
+    # rows are read from the refs: Mosaic has no dynamic_slice of a
+    # loaded value, but a dynamic pl.ds row of a ref is a plain load
+    def step(t, h):                                   # h: (1, blk_w)
+        row = pl.ds(t, 1)
+        a = a_ref[0, row, :].astype(jnp.float32)
+        x = x_ref[0, row, :].astype(jnp.float32)
+        h = jnp.exp(a) * h + x
+        y_ref[0, row, :] = h.astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_ref[...])
@@ -71,7 +68,7 @@ def rglru_scan_fwd(log_a, x, *, chunk: int = 256, blk_w: int = 512,
         ],
         out_specs=pl.BlockSpec((1, chunk, blk_w), lambda b, w, c: (b, c, w)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((blk_w,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, blk_w), jnp.float32)],
         interpret=interpret,
     )(log_a, x)
     return y

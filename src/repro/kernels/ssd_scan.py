@@ -20,15 +20,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_ref, *,
-                chunk: int):
+def _dot(a, b, contract):
+    """f32 contraction in full f32 on the MXU: the output is compared
+    with the f32 sequential recurrence, and a one-pass bf16 contraction
+    would round the operands to 8 bits first."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _ssd_kernel(x_ref, lr_ref, lc_ref, b_ref, c_ref, y_ref, st_ref,
+                state_ref, *, chunk: int):
     ci = pl.program_id(2)
     n_c = pl.num_programs(2)
 
@@ -36,36 +41,31 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_ref, *,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)            # (Q, P)
-    a = a_ref[0, :, 0].astype(jnp.float32)            # (Q,)
+    x = x_ref[0, 0].astype(jnp.float32)               # (Q, P)
+    la_r = lr_ref[0, 0]                               # (1, Q) cumsum row
+    la_c = lc_ref[0, 0]                               # (Q, 1) cumsum col
     b = b_ref[0].astype(jnp.float32)                  # (Q, N)
     c = c_ref[0].astype(jnp.float32)                  # (Q, N)
 
-    la = jnp.cumsum(a)                                # (Q,)
     # --- intra-chunk quadratic term ---
-    g = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Q, Q)
-    dd = la[:, None] - la[None, :]                    # (Q, Q) La_i - La_j
+    g = _dot(c, b, ((1,), (1,)))                      # (Q, Q)
+    dd = la_c - la_r                                  # (Q, Q) La_i - La_j
     iq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     m = jnp.where(iq >= jq, jnp.exp(dd), 0.0)
-    y = jax.lax.dot_general(g * m, x, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Q, P)
+    y = _dot(g * m, x, ((1,), (0,)))                  # (Q, P)
 
     # --- inter-chunk contribution from the carried state ---
     state = state_ref[...]                            # (P, N)
-    y += jnp.exp(la)[:, None] * jax.lax.dot_general(
-        c, state, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (Q, P)
+    y += jnp.exp(la_c) * _dot(c, state, ((1,), (1,)))  # (Q, P)
 
     # --- state update ---
-    decay_chunk = jnp.exp(la[-1])
-    w = jnp.exp(la[-1] - la)[:, None] * x             # (Q, P)
-    s_new = jax.lax.dot_general(w, b, (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (P,N)
-    state_ref[...] = state * decay_chunk + s_new
+    la_last = la_r[:, chunk - 1:]                     # (1, 1)
+    w = jnp.exp(la_last - la_c) * x                   # (Q, P)
+    s_new = _dot(w, b, ((0,), (0,)))                  # (P, N)
+    state_ref[...] = state * jnp.exp(la_last) + s_new
 
-    y_ref[0, :, 0] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     @pl.when(ci == n_c - 1)
     def _emit_state():
@@ -77,7 +77,14 @@ def ssd_scan_fwd(xh, dA_log, B_s, C_s, *, chunk: int = 128,
                  interpret: bool = False):
     """xh: (B, S, H, P) inputs pre-scaled by dt; dA_log: (B, S, H);
     B_s, C_s: (B, S, N). Returns (y (B, S, H, P) f32, state (B,H,P,N) f32).
-    S must be divisible by chunk (callers pad)."""
+    S must be divisible by chunk (callers pad).
+
+    The kernel sees heads as a leading dim, so every block's last two
+    dims are (chunk, P), (chunk, N) or (P, N): the TPU tiling needs
+    them aligned to (8, 128) or equal to the full array dims. The
+    within-chunk cumsum of dA_log is taken here and handed in twice,
+    as a (1, chunk) row and a (chunk, 1) column, so the kernel forms
+    La_i - La_j by broadcasting instead of transposing a vector."""
     B, S, H, P = xh.shape
     N = B_s.shape[-1]
     chunk = min(chunk, S)
@@ -85,26 +92,33 @@ def ssd_scan_fwd(xh, dA_log, B_s, C_s, *, chunk: int = 128,
         chunk //= 2
     n_c = S // chunk
 
+    xt = xh.transpose(0, 2, 1, 3)                     # (B, H, S, P)
+    la = jnp.cumsum(dA_log.astype(jnp.float32).reshape(B, n_c, chunk, H),
+                    axis=2).reshape(B, S, H).transpose(0, 2, 1)  # (B,H,S)
+    la_row = la[:, :, None, :]                        # (B, H, 1, S)
+    la_col = la[:, :, :, None]                        # (B, H, S, 1)
+
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     grid = (B, H, n_c)
     y, state = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, H, P), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(xh, dA_log, B_s, C_s)
-    return y, state
+    )(xt, la_row, la_col, B_s, C_s)
+    return y.transpose(0, 2, 1, 3), state

@@ -1,7 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init). Everything else follows.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the device
+# count and platform at first init). This is a compile-only tool on a
+# simulated mesh: pinning the CPU keeps this process and the per-cell
+# children it spawns (which inherit the environment) off any attached
+# accelerator. Everything else follows.
 
 import argparse
 import dataclasses

@@ -10,6 +10,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 from repro.parallel.sharding import MeshAxes
 
@@ -19,16 +20,11 @@ HBM_BW = 819e9                  # bytes/s
 ICI_BW_PER_LINK = 50e9          # bytes/s per link (~ one direction)
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """jax >= 0.5 wants explicit axis_types; older jax has no AxisType."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_axes(mesh) -> MeshAxes:
@@ -42,4 +38,5 @@ def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
                    axes: Tuple[str, ...] = ("data", "model")):
     """Small mesh for multi-device unit tests (subprocess with forced
     host device count)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

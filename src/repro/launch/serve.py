@@ -39,6 +39,7 @@ from repro.launch.cacheargs import add_cache_args, cache_overrides
 from repro.launch.train import resolve_config
 from repro.models.api import build_model
 from repro.models.transformer import RunSettings
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def build_runtime(arch: str, seed: int = 0):
@@ -125,6 +126,7 @@ def main() -> None:
                     help="write the serve report as JSON")
     add_cache_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace:
         obs.enable()

@@ -8,6 +8,7 @@ arrays with the same shardings.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -21,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models.api import ModelApi
 from repro.models.transformer import RunSettings
-from repro.optim.optimizers import Optimizer
+from repro.optim.optimizers import Optimizer, global_norm
 from repro.parallel.sharding import (MeshAxes, batch_specs, cache_specs,
                                      param_specs, with_sharding)
 
@@ -80,7 +81,8 @@ def build_settings(cfg: ModelConfig, mesh, axes: MeshAxes, *, kind: str,
 
 def make_host_train_step(api: ModelApi, optimizer: Optimizer,
                          settings: RunSettings, *, mesh=None,
-                         axes: Optional[MeshAxes] = None) -> Callable:
+                         axes: Optional[MeshAxes] = None,
+                         donate_opt_state: bool = True) -> Callable:
     """Whole-step jitted train step for the single-host jit engine —
     shared by `repro.session.TrainSession` and `repro.launch.train`.
     Signature matches what TrainLoop drives:
@@ -91,21 +93,32 @@ def make_host_train_step(api: ModelApi, optimizer: Optimizer,
     batch under the reserved "_spool_step" key — the traced scalar the
     hooks key their spool step-leases on.
 
+    The metrics carry the step's `grad_norm` (before any clipping), a
+    number that depends on the backward, unlike the loss.
+
     With a `mesh`, each numpy batch from the loader is placed with
     dp-sharded batch specs before entering the jitted step, so the
     program partitions across the mesh (params/opt state placement is
     the caller's job — `TrainSession.init` device_puts them); the spool
-    hooks then run their callbacks per shard under a shard_map."""
+    hooks then run their callbacks per shard under a shard_map.
+
+    The step donates `params` and `opt_state`: the new state is written
+    over the old, so the caller must not reuse what it passed in.
+    `donate_opt_state=False` keeps the optimizer state undonated, for
+    callers whose opt state may still be read by a spool store in
+    flight (TrainLoop's "opt_state" host offload forwards it)."""
     hooked = (settings.activation_policy == "spool"
               and settings.hook_bridge is not None)
 
-    @jax.jit
+    @functools.partial(jax.jit,
+                       donate_argnums=(0, 1) if donate_opt_state else (0,))
     def step_fn(params, opt_state, batch):
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
         if hooked:
             batch["_spool_step"] = opt_state.step
         (_, metrics), grads = jax.value_and_grad(
             api.loss, has_aux=True)(params, batch, settings)
+        metrics = dict(metrics, grad_norm=global_norm(grads))
         params, opt_state = optimizer.update(grads, opt_state, params)
         return params, opt_state, metrics
 

@@ -32,6 +32,7 @@ import time
 
 from repro.configs.base import SpoolIoConfig
 from repro.launch.cacheargs import add_cache_args, cache_overrides
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.session import TrainSession, resolve_config  # noqa: F401
 # resolve_config is re-exported for back-compat: it used to live here.
 
@@ -153,6 +154,7 @@ def main() -> None:
                          "counted when a ring fills)")
     add_cache_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:

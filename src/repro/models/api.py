@@ -257,6 +257,47 @@ def _head(params, x, cfg: ModelConfig, settings=None):
     return logits
 
 
+def _ce_terms(logits, labels):
+    """(sum nll, token count) — vocab-parallel-friendly label pick:
+    take_along_axis is a gather along the tp-sharded vocab dim and
+    makes GSPMD all-gather the logits; the masked reduction
+    partitions cleanly (Megatron's vocab-parallel cross-entropy)."""
+    mask = (labels >= 0).astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    vmask = (jnp.arange(logits.shape[-1],
+                        dtype=labels.dtype)[None, None]
+             == jnp.maximum(labels, 0)[..., None])
+    picked = jnp.sum(jnp.where(vmask, logits, 0.0), axis=-1)
+    return ((lse - picked) * mask).sum(), mask.sum()
+
+
+def head_ce_terms(params, x, labels, cfg: ModelConfig, settings=None):
+    """(sum nll, token count) of the LM head over final hidden states.
+
+    With `settings.ce_chunk` dividing the sequence, the (B, S, V) fp32
+    logits never materialise: each chunk's head matmul + CE runs under
+    remat, so backward recomputes one chunk of logits at a time. At
+    V=152k, S=4096 that is ~2.5 GB of fp32 logits per sequence (x3
+    with AD buffers). Shared by the whole-step loss and the staged
+    engine's head stage."""
+    S = labels.shape[1]
+    chunk = settings.ce_chunk if settings is not None else 0
+    if not chunk or S % chunk or S <= chunk:
+        return _ce_terms(_head(params, x, cfg, settings), labels)
+    nc = S // chunk
+    xc = x.reshape(x.shape[0], nc, chunk, -1)
+    lc = labels.reshape(labels.shape[0], nc, chunk)
+
+    @jax.checkpoint
+    def chunk_terms(args):
+        xi, li = args
+        return _ce_terms(_head(params, xi, cfg, settings), li)
+
+    nll, toks = jax.lax.map(chunk_terms,
+                            (xc.swapaxes(0, 1), lc.swapaxes(0, 1)))
+    return nll.sum(), toks.sum()
+
+
 # -------------------------------------------------------------- build
 
 def build_model(cfg: ModelConfig) -> ModelApi:
@@ -329,19 +370,6 @@ def build_model(cfg: ModelConfig) -> ModelApi:
         logits = _head(params, x, cfg, settings)
         return (logits, caches, aux) if emit_cache else (logits, aux)
 
-    def _ce_terms(logits, labels):
-        """(sum nll, token count) — vocab-parallel-friendly label pick:
-        take_along_axis is a gather along the tp-sharded vocab dim and
-        makes GSPMD all-gather the logits; the masked reduction
-        partitions cleanly (Megatron's vocab-parallel cross-entropy)."""
-        mask = (labels >= 0).astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        vmask = (jnp.arange(logits.shape[-1],
-                            dtype=labels.dtype)[None, None]
-                 == jnp.maximum(labels, 0)[..., None])
-        picked = jnp.sum(jnp.where(vmask, logits, 0.0), axis=-1)
-        return ((lse - picked) * mask).sum(), mask.sum()
-
     def forward_hidden(params, batch, settings: RunSettings):
         """Backbone only: final hidden states (pre-head), aux losses."""
         enc_states = _enc_states(params, batch, settings)
@@ -355,33 +383,9 @@ def build_model(cfg: ModelConfig) -> ModelApi:
 
     def loss(params, batch, settings: RunSettings):
         labels = batch["labels"]
-        S = labels.shape[1]
-        if settings.ce_chunk and S % settings.ce_chunk == 0 \
-                and S > settings.ce_chunk:
-            # chunked CE: the (B, S, V) fp32 logits never materialise —
-            # each chunk's head matmul + CE runs under remat, so backward
-            # recomputes one chunk of logits at a time. At V=152k, B=256,
-            # S=4096 this removes ~2.5 GB/device of fp32 logits (x3 with
-            # AD buffers) from the dry-run peak.
-            x, aux = forward_hidden(params, batch, settings)
-            nc = S // settings.ce_chunk
-            xc = x.reshape(x.shape[0], nc, settings.ce_chunk, -1)
-            lc = labels.reshape(labels.shape[0], nc, settings.ce_chunk)
-
-            @jax.checkpoint
-            def chunk_terms(args):
-                xi, li = args
-                logits = _head(params, xi, cfg, settings)
-                return _ce_terms(logits, li)
-
-            nll, toks = jax.lax.map(
-                chunk_terms, (xc.swapaxes(0, 1), lc.swapaxes(0, 1)))
-            ce = nll.sum() / jnp.maximum(toks.sum(), 1.0)
-            tokens = toks.sum()
-        else:
-            logits, aux = forward(params, batch, settings)
-            nll, tokens = _ce_terms(logits, labels)
-            ce = nll / jnp.maximum(tokens, 1.0)
+        x, aux = forward_hidden(params, batch, settings)
+        nll, tokens = head_ce_terms(params, x, labels, cfg, settings)
+        ce = nll / jnp.maximum(tokens, 1.0)
         total = ce
         metrics = {"ce": ce, "tokens": tokens}
         if "moe_lb" in aux:

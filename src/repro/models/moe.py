@@ -21,11 +21,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
+from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import activation, dense_init
-
-from repro.parallel.shmap import shard_map as _shard_map
-from jax.sharding import PartitionSpec as P
 
 
 @dataclass(frozen=True)

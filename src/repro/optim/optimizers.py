@@ -37,10 +37,14 @@ class Optimizer:
     leaf_update: Optional[Callable] = None
 
 
+def global_norm(tree) -> jnp.ndarray:
+    """f32 L2 norm over every leaf of `tree`."""
+    return jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for g in jax.tree.leaves(tree)))
+
+
 def clip_by_global_norm(grads, max_norm: float):
-    leaves = jax.tree.leaves(grads)
-    gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                      for g in leaves))
+    gn = global_norm(grads)
     scale = jnp.minimum(1.0, max_norm / jnp.maximum(gn, 1e-9))
     return jax.tree.map(lambda g: (g.astype(jnp.float32) * scale)
                         .astype(g.dtype), grads), gn

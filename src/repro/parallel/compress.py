@@ -19,9 +19,8 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel.shmap import shard_map as _shard_map
 
 
 def init_error_state(grads_like) -> Any:

@@ -22,9 +22,8 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel.shmap import shard_map as _shard_map
 
 
 def pipeline_apply(stage_fn: Callable, params_stacked, x_mb, mesh,

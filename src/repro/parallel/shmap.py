@@ -1,36 +1,16 @@
-"""shard_map compatibility shim + mesh bookkeeping helpers.
+"""Mesh bookkeeping helpers.
 
-jax renamed `check_rep` to `check_vma` (and moved shard_map out of
-experimental) across versions; callers here always say `check_vma` and
-this wrapper translates to whatever the installed jax understands.
-
-The helpers below are the mesh arithmetic the sharded offload hooks
+These are the mesh arithmetic the sharded offload hooks
 (`repro.core.hooks`) need: a linearized per-device shard index computed
 *inside* a shard_map body, and the local (per-shard) shape implied by a
 PartitionSpec.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax import shard_map as _impl          # jax >= 0.4.35
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _impl
-
-_PARAMS = set(inspect.signature(_impl).parameters)
-
-
-def shard_map(f, **kwargs):
-    if "check_vma" in kwargs and "check_vma" not in _PARAMS:
-        flag = kwargs.pop("check_vma")
-        if "check_rep" in _PARAMS:
-            kwargs["check_rep"] = flag
-    return _impl(f, **kwargs)
 
 
 def mesh_size(mesh) -> int:

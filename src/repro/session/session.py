@@ -305,7 +305,8 @@ class TrainSession:
             else:
                 self._step_fn = make_host_train_step(
                     self.api, self.optimizer, self.settings,
-                    mesh=self.mesh, axes=self.mesh_axes)
+                    mesh=self.mesh, axes=self.mesh_axes,
+                    donate_opt_state=(mode != "opt_state"))
 
     # ------------------------------------------------------------ state
 

@@ -68,6 +68,20 @@ def test_backend_blob_roundtrip(kind, tmp_path):
 
 
 @pytest.mark.parametrize("kind", BACKEND_KINDS)
+def test_backend_shard_key_roundtrip(kind, tmp_path):
+    """Per-shard spool keys carry a "/" (`jit0/s1_s3`); a file-backed
+    backend must store them as one flat file, not a missing subdir."""
+    b = make_backend(kind, tmp_path)
+    data = os.urandom(10_000)
+    for key in ("jit0/s1_s3", "jit0%2Fs1_s3"):
+        b.write(key, data + key.encode())
+    for key in ("jit0/s1_s3", "jit0%2Fs1_s3"):
+        assert b.read(key) == data + key.encode()
+        b.delete(key)
+    b.close()
+
+
+@pytest.mark.parametrize("kind", BACKEND_KINDS)
 def test_backend_reports_tier_bandwidths(kind, tmp_path):
     b = make_backend(kind, tmp_path)
     b.write("k", b"x" * 4096)

@@ -1,15 +1,21 @@
 """Fault-tolerance tests on the TrainLoop: checkpoint/restart determinism,
 preemption handling, straggler detection."""
 import os
+import subprocess
+import sys
 import tempfile
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.data.pipeline import ShardedLoader, SyntheticMarkovLM
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.trainer import StragglerWatchdog, TrainLoop, TrainState
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 def _quadratic_setup(ckpt_dir, metrics=None, slow_steps=()):
@@ -123,3 +129,36 @@ def test_tokens_per_s_masks_padding():
     rec = json.loads(open(path).readline())
     tokens = rec["tokens_per_s"] * rec["step_time_s"]
     assert abs(tokens - 6) < 1e-6 * 6, rec
+
+
+def test_compile_cache_default_dir(monkeypatch):
+    """Without JAX_COMPILATION_CACHE_DIR the cache is the checkout's
+    fixed `.jax_cache` (no pid, time or temp name in the path)."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        got = enable_compile_cache()
+        assert got == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_follows_env(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, compiled programs land there
+    and the helper sets no other directory."""
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [str(REPO / "src"), os.environ.get("PYTHONPATH", "")]))
+    code = ("import jax\n"
+            "from repro.runtime.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "jax.jit(lambda x: x * 2 + 1)(1.0).block_until_ready()\n")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == [str(tmp_path), str(tmp_path)]
+    assert any(tmp_path.iterdir())

@@ -72,6 +72,17 @@ def test_both_engines_finite_matching_losses(parity):
     np.testing.assert_allclose(ls, lj, rtol=5e-3)
 
 
+def test_both_engines_report_matching_grad_norm(parity):
+    """Both engines report each step's gradient norm; the first step
+    starts from the same params and batch, so the norms agree as closely
+    as the losses do."""
+    gs, gj = ([r.extra["grad_norm"] for r in parity[e]["result"].reports]
+              for e in ("staged", "jit"))
+    assert len(gs) == len(gj) == 3
+    assert np.all(np.isfinite(gs)) and np.all(np.array(gs) > 0)
+    np.testing.assert_allclose(gs[0], gj[0], rtol=5e-3)
+
+
 def test_reports_unified_schema(parity):
     for engine in ("staged", "jit"):
         reports = parity[engine]["result"].reports

@@ -323,6 +323,7 @@ def test_failed_store_forwarding_counted_once():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert spool.stats.bytes_forwarded == nbytes, \
         "peek-then-fetch of a failed store must count ONE forwarding"
+    assert spool.stats.num_forwarded == 1
     spool.drop("k")
     spool.close()
 

@@ -112,6 +112,7 @@ def test_failed_store_peek_then_fetch_counts_one_forwarding():
         _assert_tree_equal(tree, tx.fetch(0))
         tx.drop(0)
     assert spool.stats.bytes_forwarded == _tree_bytes(tree)
+    assert spool.stats.num_forwarded == 1
     assert spool.stats.store_retries == 2    # attempts 2 and 3
     spool.close()
 
@@ -188,6 +189,7 @@ def test_delayed_store_completion_forwarding_and_cancel():
         tx.drop(1)
     spool.wait_io()
     assert spool.stats.bytes_forwarded == _tree_bytes(t0) + _tree_bytes(t1)
+    assert spool.stats.num_forwarded == 2
     assert spool.stats.stores_canceled >= 1
     assert spool.stats.num_stores + spool.stats.stores_canceled == 2
     spool.close()

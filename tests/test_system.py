@@ -135,6 +135,33 @@ def test_adaptive_plan_exists_after_profile_step(runs):
     assert not rep.plan.offload[-1]
 
 
+class _FullDevice:
+    """A device whose memory is reported as all in use."""
+
+    def memory_stats(self):
+        return {"bytes_in_use": 1 << 34, "bytes_limit": 1 << 34}
+
+
+def test_staged_forward_waits_for_device_room():
+    """When the device reports too little room for the next stage's
+    residuals, the forward drains the store queue before running it;
+    the CPU reports no limit and never waits. Waiting changes no math."""
+    cfg = small_gpt(128, 3)
+    batch = _batch(cfg)
+    waits, losses = {}, {}
+    for full in (False, True):
+        api, tr, params, opt_state = _setup(cfg, "offload")
+        if full:
+            tr._device = _FullDevice()
+        _, _, rep = tr.train_step(params, opt_state, [batch])
+        waits[full], losses[full] = tr.device_room_waits, rep.loss
+        tr.close()
+    assert waits[False] == 0
+    # every stage after the first has a profiled size to make room for
+    assert waits[True] == len(tr._stages) - 1
+    assert losses[True] == losses[False]
+
+
 def test_staged_matches_jit_training():
     """The staged trainer is numerically the same training algorithm as a
     whole-step jit (the system's central correctness invariant)."""
@@ -148,7 +175,9 @@ def test_staged_matches_jit_training():
     params = api.init(jax.random.key(7))
     tr = StagedTrainer(api, settings, opt, strategy="offload",
                        min_offload_elements=MIN_OFF)
-    p_staged, os_staged = params, opt.init(params)
+    # both steps donate their inputs: each run starts from its own copy
+    p_staged = jax.tree.map(jnp.copy, params)
+    os_staged = opt.init(p_staged)
     for _ in range(2):
         p_staged, os_staged, rep = tr.train_step(p_staged, os_staged,
                                                  [batch])
