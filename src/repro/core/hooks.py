@@ -190,25 +190,29 @@ class HookBridge:
         self._lock = threading.RLock()
         self._cv = threading.Condition(self._lock)
         self._txs: Dict[str, SpoolStepTransaction] = {}
-        self._shard_stats: Dict[Any, Dict[str, int]] = {}
+        self._shard_stats: Dict[Any, Dict[str, float]] = {}
 
     @property
     def stats(self):
         return self.spool.stats
 
-    def stats_by_shard(self) -> Dict[Any, Dict[str, int]]:
+    def stats_by_shard(self) -> Dict[Any, Dict[str, float]]:
         """Per-shard callback traffic: offloads / fetches /
-        replica_skips counts and logical bytes in each direction. The
-        key is the shard id (None on a single device)."""
+        replica_skips counts, logical bytes in each direction, and the
+        callbacks' host seconds: `offload_s` and `fetch_s` whole, and
+        `copy_s` the part of `offload_s` spent copying the operands.
+        The key is the shard id (None on a single device)."""
         with self._lock:
             return {k: dict(v) for k, v in self._shard_stats.items()}
 
-    def _note(self, shard, field: str, n: int = 1) -> None:
+    def _note(self, shard, **deltas) -> None:
         with self._lock:
             rec = self._shard_stats.setdefault(shard, {
                 "offloads": 0, "fetches": 0, "replica_skips": 0,
-                "degraded_fetches": 0, "bytes_in": 0, "bytes_out": 0})
-            rec[field] += n
+                "degraded_fetches": 0, "bytes_in": 0, "bytes_out": 0,
+                "copy_s": 0.0, "offload_s": 0.0, "fetch_s": 0.0})
+            for field, n in deltas.items():
+                rec[field] += n
 
     def _step_id(self, step: int, shard) -> str:
         base = f"{self._prefix}{step}"
@@ -235,15 +239,20 @@ class HookBridge:
         returns, and the spool's store worker runs after that. A plain
         owned memcpy also never touches the jax runtime — a device
         thread must not block on jax's async machinery mid-step."""
+        t0 = time.perf_counter()
         with obs.span("hook.offload", cat="hook", step=step, stage=stage,
                       shard=shard) as sp:
-            arrays = [np.array(a, copy=True) for a in arrays]
+            with obs.span("hook.copy", cat="hook", step=step,
+                          stage=stage, shard=shard) as csp:
+                arrays = [np.array(a, copy=True) for a in arrays]
+                nbytes = int(sum(a.nbytes for a in arrays))
+                csp.set(bytes=nbytes)
+            t_copy = time.perf_counter()
             tx = self._tx(self._step_id(step, shard))
             tx.offload(stage, arrays, consumers=consumers)
-            nbytes = int(sum(a.nbytes for a in arrays))
             sp.set(bytes=nbytes)
-        self._note(shard, "offloads")
-        self._note(shard, "bytes_in", nbytes)
+        self._note(shard, offloads=1, bytes_in=nbytes, copy_s=t_copy - t0,
+                   offload_s=time.perf_counter() - t0)
         with self._cv:
             self._cv.notify_all()
 
@@ -258,7 +267,7 @@ class HookBridge:
                 self.offload(step, stage, arrays, shard=shard,
                              consumers=n_replicas)
             else:
-                self._note(shard, "replica_skips")
+                self._note(shard, replica_skips=1)
                 obs.instant("hook.replica_skip", cat="hook", step=step,
                             stage=stage, shard=shard, replica=replica)
         else:
@@ -274,6 +283,7 @@ class HookBridge:
         consumed. Waits (bounded) for the forward offload callback —
         on a mesh the store and fetch arrive on different host-callback
         threads and their cross-device order is not guaranteed."""
+        t0 = time.perf_counter()
         step_id = self._step_id(step, shard)
         # only a sharded fetch may legitimately beat its store callback
         # (they run on different device threads); on one device the
@@ -310,12 +320,12 @@ class HookBridge:
             arrays = [np.asarray(a) for a in out]
             nbytes = int(sum(a.nbytes for a in arrays))
             fsp.set(bytes=nbytes)
-        self._note(shard, "fetches")
-        self._note(shard, "bytes_out", nbytes)
         with self._lock:
             if not tx.live_stages and self._txs.get(step_id) is tx:
                 del self._txs[step_id]
                 tx.close()
+        self._note(shard, fetches=1, bytes_out=nbytes,
+                   fetch_s=time.perf_counter() - t0)
         return arrays
 
     def sharded_fetch(self, step: int, stage: int, *, shard: int,
@@ -338,8 +348,7 @@ class HookBridge:
             return (np.int32(1), *arrays)
         except (RuntimeError, OSError, KeyError) as e:
             self.spool.stats.fetch_fallbacks += 1
-            self._note(shard, "degraded_fetches")
-            obs.count("resilience.fetch_fallback")
+            self._note(shard, degraded_fetches=1)
             obs.instant("resilience.fetch_fallback", cat="resilience",
                         step=step, stage=stage, shard=shard,
                         error=repr(e))

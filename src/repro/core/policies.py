@@ -248,7 +248,6 @@ class AdaptivePolicy(OffloadPolicy):
             self.replans += 1
             n_off = sum(self.plan.offload)
         if obs.is_enabled():
-            obs.count("resilience.replan")
             obs.instant("resilience.replan", cat="resilience",
                         trigger=event.kind, op=event.op,
                         bw_scale=round(scale, 4),

@@ -24,6 +24,11 @@ class StepReport:
     step: int = -1                     # optimizer step index (-1: unset)
     engine: str = ""                   # "staged" | "jit"
     tokens_per_s: float = 0.0
+    # jit engine: host seconds from the step call until it returned,
+    # the rest of step_time being the wait for the device (0: staged)
+    dispatch_time: float = 0.0
+    # XLA backend compiles during the step (repro.obs.compiles)
+    compiles: int = 0
     # engine-specific scalar metrics (jit: the step's full aux dict —
     # ce, tokens, moe_lb/moe_z on MoE archs, ...); merged into the JSONL
     extra: Dict[str, float] = field(default_factory=dict)
@@ -32,7 +37,7 @@ class StepReport:
     obs: Optional[Dict[str, Any]] = None
     # per-shard HookBridge traffic deltas for this step, keyed by shard
     # id ("global" on a single device)
-    shard_stats: Optional[Dict[str, Dict[str, int]]] = None
+    shard_stats: Optional[Dict[str, Dict[str, float]]] = None
     # cache-manager block for this step (managed backend only): counter
     # deltas + residency gauges from CacheManager.metrics_delta; emitted
     # with a cache_ prefix
@@ -55,6 +60,8 @@ class StepReport:
             "loss": float(self.loss),
             "step_time_s": float(self.step_time),
             "tokens_per_s": float(self.tokens_per_s),
+            "dispatch_time_s": float(self.dispatch_time),
+            "compiles": int(self.compiles),
             "peak_activation_bytes": int(self.peak_activation_bytes),
             "backward_begin_bytes": int(self.backward_begin_bytes),
         }

@@ -132,6 +132,10 @@ class SpoolStats:
     stores_canceled: int = 0
     store_time: float = 0.0
     load_time: float = 0.0
+    # seconds inside backend.write_parts, summed over store workers:
+    # store_time without encode, retry back-off or the bandwidth cap,
+    # so bytes_offloaded / write_time is the rate one write sees
+    write_time: float = 0.0
     num_stores: int = 0
     num_loads: int = 0
     # time the *consumer* (backward pass) spent blocked waiting for a
@@ -183,7 +187,7 @@ class SpoolStats:
 
 class _Job:
     __slots__ = ("key", "arrays", "state", "cond", "kind", "orphaned",
-                 "error", "reg_keys", "prefetched")
+                 "error", "reg_keys", "prefetched", "t_enq", "cause")
 
     def __init__(self, key, arrays, kind):
         self.key = key
@@ -203,6 +207,10 @@ class _Job:
         # the buffer free would let a recycled allocation false-dedup
         # against a dead entry
         self.reg_keys: tuple = ()
+        # enqueue time (the worker's span records how long the job
+        # queued) and the span that enqueued it (its `cause`)
+        self.t_enq = time.perf_counter()
+        self.cause = obs.current_span()
 
 
 class SpoolStepTransaction:
@@ -865,7 +873,6 @@ class ActivationSpool:
                 else:
                     self.stats.load_retries += 1
                 if obs.is_enabled():
-                    obs.count("resilience.retry")
                     obs.instant("resilience.retry", cat="resilience",
                                 op=op, key=str(key), attempt=attempt,
                                 error=repr(e))
@@ -901,9 +908,11 @@ class ActivationSpool:
                 return
             job.state = RUNNING
         t0 = time.perf_counter()
+        queued_ms = (t0 - job.t_enq) * 1e3
         if job.kind == "store":
-            with obs.span("spool.store", cat="spool",
-                          key=str(job.key)) as store_sp:
+            with obs.span("spool.store", cat="spool", key=str(job.key),
+                          cause=job.cause,
+                          queued_ms=queued_ms) as store_sp:
                 arrays = [np.asarray(a) for a in job.arrays]
                 # vectored store: the serde part list flows through the
                 # codec container straight to backend.write_parts — with
@@ -917,10 +926,17 @@ class ActivationSpool:
                              else p.nbytes for p in parts)
                 # memoryview parts are re-readable, so a retry re-issues
                 # the same vectored write without re-encoding
-                self._with_retry(
-                    "write", job.key,
-                    lambda: self.backend.write_parts(str(job.key),
-                                                     parts))
+                write_s = 0.0
+
+                def write():
+                    nonlocal write_s
+                    tw = time.perf_counter()
+                    try:
+                        self.backend.write_parts(str(job.key), parts)
+                    finally:
+                        write_s += time.perf_counter() - tw
+
+                self._with_retry("write", job.key, write)
                 dt = time.perf_counter() - t0
                 if self._bw:
                     min_t = nbytes / self._bw
@@ -932,6 +948,7 @@ class ActivationSpool:
             self.stats.bytes_offloaded_logical += \
                 sum(a.nbytes for a in arrays)
             self.stats.store_time += dt
+            self.stats.write_time += write_s
             self.stats.num_stores += 1
             # registry entries must not outlive the buffers they track:
             # release BEFORE freeing, so a recycled address can never
@@ -964,7 +981,9 @@ class ActivationSpool:
             # lease lives until the record is dropped (fetch copies on
             # demand when it materializes device arrays).
             lease = None
-            with obs.span("spool.load", cat="spool", key=key) as load_sp:
+            with obs.span("spool.load", cat="spool", key=key,
+                          cause=job.cause,
+                          queued_ms=queued_ms) as load_sp:
                 # RAM-backed stores hand the blob back by reference — a
                 # pooled staging copy would only ADD a memcpy there
                 nbytes = None if self.backend.zero_copy_read \

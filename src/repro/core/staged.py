@@ -487,7 +487,6 @@ class StagedTrainer:
                         raise
                     self.spool.stats.fetch_fallbacks += 1
                     if obs.is_enabled():
-                        obs.count("resilience.fetch_fallback")
                         obs.instant("resilience.fetch_fallback",
                                     cat="resilience", stage=stage.name,
                                     key=tx.key(si), error=repr(e))

@@ -277,7 +277,6 @@ class StripedBackend(StorageBackend):
                 if dev != default:
                     self.rebalanced_chunks += 1
             if dev != default and obs.is_enabled():
-                obs.count("resilience.rebalance")
                 obs.instant("resilience.rebalance", cat="resilience",
                             key=key, chunk=i, frm=default, to=dev)
             return dev

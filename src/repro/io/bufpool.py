@@ -111,11 +111,9 @@ class AlignedBufferPool:
                 mm = bucket.pop()
                 self._free_bytes -= cap
                 self.hits += 1
-                obs.count("pool.hit")
                 return PooledBuffer(self, mm, cap, nbytes)
             self.misses += 1
             self.bytes_allocated += cap
-        obs.count("pool.miss")
         # a miss is a fresh mmap whose pages fault on first touch — the
         # exact churn MemAscend measures, so it earns a timeline mark
         obs.instant("pool.miss", cat="pool", bytes=cap)
@@ -129,7 +127,6 @@ class AlignedBufferPool:
                 self._free_bytes += cap
                 return
             self.trimmed += 1
-        obs.count("pool.trim")
         try:
             mm.close()
         except BufferError:

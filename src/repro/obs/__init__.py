@@ -7,13 +7,15 @@ overlap analyzer that turns a trace window into I/O-hidden fraction and
 stall attribution (`repro.obs.overlap`).
 
 Call sites use the module-level helpers (`span`/`instant`/`count`/
-`gauge`), which are a None-check no-op until `enable()` installs a
-tracer — usually via `TrainSession(trace=...)` or `--trace`.
+`gauge`/`current_span`), which are a None-check no-op until `enable()`
+installs a tracer — usually via `TrainSession(trace=...)` or `--trace`.
+`repro.obs.compiles.CompileCounter` counts XLA backend compiles.
 """
 from repro.obs.tracer import (
     DEFAULT_RING_SIZE,
     Tracer,
     count,
+    current_span,
     disable,
     enable,
     gauge,
@@ -30,6 +32,7 @@ __all__ = [
     "Tracer",
     "analyze",
     "count",
+    "current_span",
     "disable",
     "enable",
     "gauge",

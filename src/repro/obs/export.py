@@ -25,7 +25,7 @@ from __future__ import annotations
 import json
 from typing import Any, Dict, List, Optional, Union
 
-from repro.obs.tracer import Tracer
+from repro.obs.tracer import GAUGE, Tracer
 
 PID_THREADS = 0
 PID_SHARDS = 1
@@ -67,6 +67,13 @@ def trace_events(tracer: Tracer) -> List[Dict[str, Any]]:
                 "tid": ring.ring_id,
                 "ts": (ts_ns - t0) / 1e3,
             }
+            if dur_ns == GAUGE:
+                # a counter track per gauge name (Perfetto keys
+                # counter tracks by pid and name)
+                base["ph"] = "C"
+                base["args"] = {"value": args["value"]}
+                events.append(base)
+                continue
             if dur_ns >= 0:
                 base["ph"] = "X"
                 base["dur"] = dur_ns / 1e3
@@ -101,8 +108,8 @@ def trace_events(tracer: Tracer) -> List[Dict[str, Any]]:
             events.append(_meta(PID_TIERS, tid, "thread_name",
                                 f"tier {kind}"))
 
-    # counters become one "C" sample at export time (rates over the run;
-    # the per-step series lives in the metrics JSONL, not the trace)
+    # the counter table becomes one "C" sample at export time (totals
+    # over the run; gauges also have their own tracks above)
     counters = tracer.counters()
     if counters:
         events.append({"name": "counters", "ph": "C", "pid": PID_THREADS,
@@ -169,6 +176,12 @@ def validate_trace(trace: Union[str, Dict[str, Any]],
                 errors.append(f"{where}: complete event needs dur >= 0")
         if ph == "M" and "name" not in ev.get("args", {}):
             errors.append(f"{where}: metadata event needs args.name")
+        if ph == "C":
+            cargs = ev.get("args")
+            if not isinstance(cargs, dict) or not cargs or not all(
+                    isinstance(v, (int, float)) for v in cargs.values()):
+                errors.append(f"{where}: counter event needs numeric "
+                              f"args")
         if not isinstance(ev.get("ts", 0), (int, float)) \
                 or ev.get("ts", 0) < 0:
             errors.append(f"{where}: ts must be a non-negative number")

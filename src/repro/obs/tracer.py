@@ -11,24 +11,34 @@ consumer actually blocked. This tracer is the substrate:
   * span (begin/end, recorded as one complete event at exit) and
     instant events, timestamped with `time.perf_counter_ns` (monotonic,
     comparable across threads of one process);
+  * causality: every span gets a process-unique `id` and records as
+    `parent` the span open around it on its thread; work handed to
+    another thread carries `current_span()` of the hand-off so the
+    worker's span can name it as its `cause`;
   * bounded memory: a full ring overwrites its oldest events and counts
     every overwrite (`dropped` is exact: `max(0, total - capacity)`);
   * a thread-safe counter/gauge table (`add`/`set_gauge`/`counters`)
     for rates the timeline cannot express (prefetch hit/late/ghost,
-    pool hits, queue backlogs).
+    queue backlogs); a gauge also pushes a timestamped sample into the
+    ring, so a queue depth reads as a track over time.
 
-The module-level helpers (`span`, `instant`, `count`) are the
-always-compiled-in call sites the rest of the repo uses: when no tracer
-is enabled they cost one global read and a None check, so tracing can
-stay wired into the spool/backend/engine hot paths permanently.
+The module-level helpers (`span`, `instant`, `count`, `gauge`,
+`current_span`) are the always-compiled-in call sites the rest of the
+repo uses: when no tracer is enabled they cost one global read and a
+None check, so tracing can stay wired into the spool/backend/engine hot
+paths permanently.
 
 Event layout (plain tuples, no classes, for append speed):
 
     (name, cat, ts_ns, dur_ns, args)    dur_ns >= 0  -> complete span
     (name, cat, ts_ns, -1,     args)    instant event
+    (name, cat, ts_ns, -2,     args)    gauge sample, args {"value": v}
+
+A span's args carry its `id` and, when one was open, its `parent`.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -39,6 +49,14 @@ DEFAULT_RING_SIZE = 1 << 16
 
 TraceEvent = Tuple[str, str, int, int, dict]
 
+#: `dur_ns` of an instant event and of a gauge sample
+INSTANT = -1
+GAUGE = -2
+
+# span ids, unique within the process (next() on a count is atomic
+# under the GIL, so no lock)
+_SPAN_IDS = itertools.count(1)
+
 
 class _Ring:
     """One thread's bounded event buffer. Appended only by the owning
@@ -46,7 +64,7 @@ class _Ring:
     consistent prefix (CPython list-slot stores are atomic)."""
 
     __slots__ = ("events", "capacity", "total", "ring_id", "tid",
-                 "thread_name", "open_depth")
+                 "thread_name", "stack")
 
     def __init__(self, capacity: int, ring_id: int, tid: int,
                  thread_name: str):
@@ -59,7 +77,12 @@ class _Ring:
         self.ring_id = ring_id
         self.tid = tid
         self.thread_name = thread_name
-        self.open_depth = 0         # spans entered but not yet exited
+        self.stack: List[int] = []  # ids of spans entered, not exited
+
+    @property
+    def open_depth(self) -> int:
+        """Spans entered but not yet exited on this thread."""
+        return len(self.stack)
 
     def push(self, ev: TraceEvent) -> None:
         if self.total < self.capacity:
@@ -85,7 +108,8 @@ class _Ring:
 class _Span:
     """Context manager recording one complete ("X") event at exit."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ring")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ring",
+                 "_id")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: dict):
@@ -95,8 +119,12 @@ class _Span:
         self._args = args
 
     def __enter__(self) -> "_Span":
-        self._ring = self._tracer._ring()
-        self._ring.open_depth += 1
+        self._ring = ring = self._tracer._ring()
+        self._id = sid = next(_SPAN_IDS)
+        self._args["id"] = sid
+        if ring.stack:
+            self._args["parent"] = ring.stack[-1]
+        ring.stack.append(sid)
         self._t0 = self._tracer._clock()
         return self
 
@@ -107,7 +135,10 @@ class _Span:
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = self._tracer._clock()
         ring = self._ring
-        ring.open_depth -= 1
+        if ring.stack[-1] == self._id:
+            ring.stack.pop()
+        else:                       # exited out of order (manual exit)
+            ring.stack.remove(self._id)
         ring.push((self._name, self._cat, self._t0, t1 - self._t0,
                    self._args))
 
@@ -169,16 +200,25 @@ class Tracer:
 
     def instant(self, name: str, cat: str = "",
                 args: Optional[dict] = None) -> None:
-        self._ring().push((name, cat, self._clock(), -1,
+        self._ring().push((name, cat, self._clock(), INSTANT,
                            args or {}))
+
+    def current_span(self) -> Optional[int]:
+        """Id of the innermost span open on the calling thread."""
+        stack = self._ring().stack
+        return stack[-1] if stack else None
 
     def add(self, name: str, n: float = 1) -> None:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + n
 
     def set_gauge(self, name: str, value: float) -> None:
+        """Latest value in the counter table, and a timestamped sample
+        on the calling thread's ring."""
         with self._lock:
             self._counters[name] = value
+        self._ring().push((name, "gauge", self._clock(), GAUGE,
+                           {"value": value}))
 
     # -------------------------------------------------------- snapshots
 
@@ -271,6 +311,16 @@ def instant(name: str, cat: str = "", **args: Any) -> None:
     t = _TRACER
     if t is not None:
         t.instant(name, cat, args)
+
+
+def current_span() -> Optional[int]:
+    """Id of the innermost span open on this thread (None when tracing
+    is off or no span is open): what a hand-off to another thread
+    carries so the receiving span can record it as its `cause`."""
+    t = _TRACER
+    if t is None:
+        return None
+    return t.current_span()
 
 
 def count(name: str, n: float = 1) -> None:

@@ -182,8 +182,8 @@ class BackendHealth:
                         consec=ev.consecutive_failures,
                         latency_ratio=round(ev.latency_ratio, 3),
                         error=ev.error or "")
-            obs.gauge("resilience.health",
-                      _STATUS_CODE[self.status], backend=ev.backend)
+            obs.gauge(f"resilience.health.{ev.backend}",
+                      _STATUS_CODE[self.status])
         with self._lock:
             subs = list(self._subs)
         for cb in subs:

@@ -129,6 +129,9 @@ class TrainLoop:
         self.host_offload = (host_offload if spool is not None
                              else "none")
         self.on_step = on_step
+        # host seconds the last step's dispatch took: the step_fn call
+        # (opt-state fetch included) until it returned, before the wait
+        self.dispatch_time = 0.0
         self._opt_tx = None          # live SpoolStepTransaction, if any
         self._preempted = False
         self._metrics_f = open(metrics_path, "a") if metrics_path else None
@@ -209,27 +212,36 @@ class TrainLoop:
         it = iter(self.loader)
         target = self.state.step + num_steps
         while self.state.step < target and not self._preempted:
+            step = self.state.step
             try:
-                batch = next(it)
+                with obs.span("loader.next", cat="engine", step=step):
+                    batch = next(it)
             except StopIteration:
                 # a finite loader ran dry: end the loop cleanly — the
                 # final checkpoint and the staged-opt-state
                 # rematerialization below must still run
                 break
+            # the step is two spans, not one around both: the profiler
+            # trace names a device idle gap by the host span covering
+            # it, and a span around both phases would take the name of
+            # every gap that straddles them
             t0 = time.perf_counter()
-            with obs.span("engine.step", cat="engine",
-                          step=self.state.step, engine="jit"):
+            with obs.span("engine.dispatch", cat="engine", step=step):
                 params, opt_state, metrics = self.step_fn(
                     self.state.params, self._acquire_opt_state(), batch)
+            t_dispatched = time.perf_counter()
+            with obs.span("engine.wait", cat="engine", step=step):
                 jax.block_until_ready(jax.tree.leaves(params)[0])
             dt = time.perf_counter() - t0
+            self.dispatch_time = t_dispatched - t0
             opt_state = self._stage_opt_state(opt_state,
                                               self.state.step + 1)
             self.state = TrainState(self.state.step + 1, params, opt_state)
             self.watchdog.record(self.state.step, dt)
             self._log(metrics, dt, batch)
             if self.on_step:
-                self.on_step(self.state.step, dt, metrics, batch)
+                with obs.span("session.report", cat="engine", step=step):
+                    self.on_step(self.state.step, dt, metrics, batch)
             if self.ckpt_every and \
                     self.state.step % self.ckpt_every == 0:
                 self._save()

@@ -52,6 +52,7 @@ from repro.data.pipeline import ShardedLoader, SyntheticMarkovLM
 from repro.launch.steps import make_host_train_step
 from repro.models.api import build_model
 from repro.models.transformer import RunSettings
+from repro.obs.compiles import CompileCounter
 from repro.parallel.sharding import (MeshAxes, param_specs,
                                      spec_tree_for_optstate)
 from repro.optim.optimizers import Optimizer, adamw, sgd
@@ -307,6 +308,11 @@ class TrainSession:
                     self.api, self.optimizer, self.settings,
                     mesh=self.mesh, axes=self.mesh_axes,
                     donate_opt_state=(mode != "opt_state"))
+        # backend compiles, always on: a step's count runs from the end
+        # of the previous step's report, so a compile inside an
+        # on_report callback is charged to no step
+        self._compiles = CompileCounter()
+        self._compiles_mark = 0
 
     # ------------------------------------------------------------ state
 
@@ -361,6 +367,7 @@ class TrainSession:
             raise RuntimeError("session is closed")
         self.init()
         start = len(self.reports)   # result carries THIS run's reports
+        self._compiles_mark = self._compiles.count
         if self.engine == "staged":
             self._run_staged(num_steps, resume=resume,
                              on_report=on_report)
@@ -494,6 +501,7 @@ class TrainSession:
                 params, opt_state, batches)
             step += 1
             rep.step = step
+            rep.compiles = self._compiles.count - self._compiles_mark
             (rep.stats, rep.shard_stats, rep.obs, rep.cache,
              rep.resilience) = self._step_deltas()
             tokens = sum(_batch_tokens(b) for b in batches)
@@ -501,6 +509,7 @@ class TrainSession:
                 if rep.step_time else 0.0
             self._state = TrainState(step, params, opt_state)
             self._emit(rep, on_report)
+            self._compiles_mark = self._compiles.count
             if self.ckpt_every and step % self.ckpt_every == 0:
                 self._staged_save()
         self._staged_save(final=True)
@@ -509,6 +518,7 @@ class TrainSession:
 
     def _run_jit(self, num_steps, *, resume, on_report):
         def on_step(step, dt, metrics, batch):
+            compiles = self._compiles.count - self._compiles_mark
             tokens = _batch_tokens(batch)
             extra = {}
             for k, v in (metrics or {}).items():
@@ -526,11 +536,13 @@ class TrainSession:
             rep = StepReport(
                 loss=extra.get("loss", float("nan")),
                 step_time=dt, step=step, engine="jit",
-                stats=stats_d,
+                dispatch_time=self._loop.dispatch_time,
+                compiles=compiles, stats=stats_d,
                 tokens_per_s=tokens / dt if dt else 0.0,
                 extra=extra, obs=obs_d, shard_stats=shard_d,
                 cache=cache_d, resilience=resil_d)
             self._emit(rep, on_report)
+            self._compiles_mark = self._compiles.count
 
         if self._loop is None:
             self._loop = TrainLoop(
@@ -557,6 +569,7 @@ class TrainSession:
         if self._closed:
             return
         self._closed = True
+        self._compiles.close()
         if self.trainer is not None:
             self.trainer.close()
         if self._loop is not None:

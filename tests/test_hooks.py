@@ -75,7 +75,8 @@ def hooked_baseline():
     with _session("jit", io=SpoolIoConfig(
             backend="mem", host_offload="activations")) as sess:
         result = sess.run(3)
-        return {"losses": result.losses, "params": result.state.params}
+        return {"losses": result.losses, "params": result.state.params,
+                "reports": result.reports}
 
 
 def test_jit_activations_matches_no_offload_baseline(jit_baseline,
@@ -112,6 +113,39 @@ def test_jit_activations_matches_no_offload_baseline(jit_baseline,
     assert stats.num_stores > 0
     # every step lease was consumed: no records strand on the spool
     assert not leftover
+
+
+def test_hook_seconds_reach_shard_stats_per_step(hooked_baseline):
+    """The bridge's always-on callback clocks — `copy_s` inside
+    `offload_s`, and `fetch_s` — arrive in every step's `shard_stats`
+    as per-step deltas beside the byte counters."""
+    for rep in hooked_baseline["reports"]:
+        g = rep.shard_stats["global"]
+        assert g["offloads"] > 0 and g["fetches"] == g["offloads"]
+        assert 0.0 < g["copy_s"] <= g["offload_s"]
+        assert g["fetch_s"] > 0.0
+        # a delta, not the run so far: no step holds more than the
+        # wall time of its own step
+        assert g["offload_s"] + g["fetch_s"] <= rep.step_time
+        assert rep.to_metrics()["shards"]["global"]["copy_s"] == \
+            g["copy_s"]
+
+
+def test_bridge_times_copy_inside_offload():
+    spool = ActivationSpool(HostMemoryBackend(), min_offload_elements=4,
+                            store_threads=1, load_threads=1)
+    bridge = HookBridge(spool)
+    arrays = [np.ones((1 << 20,), np.float32)]       # 4 MB to copy
+    bridge.offload(0, 0, arrays)
+    after_offload = bridge.stats_by_shard()[None]
+    assert 0.0 < after_offload["copy_s"] <= after_offload["offload_s"]
+    assert after_offload["fetch_s"] == 0.0
+    np.testing.assert_array_equal(bridge.fetch(0, 0)[0], arrays[0])
+    rec = bridge.stats_by_shard()[None]
+    assert rec["fetch_s"] > 0.0
+    assert rec["copy_s"] == after_offload["copy_s"]
+    assert rec["bytes_in"] == rec["bytes_out"] == arrays[0].nbytes
+    spool.close()
 
 
 def test_jit_vs_staged_parity_with_activations():

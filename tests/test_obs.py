@@ -133,6 +133,121 @@ def test_disabled_fast_path_is_noop():
         obs_tracer._TRACER = prev
 
 
+def test_spans_record_ids_and_their_parent_on_the_thread():
+    """Every span gets a unique id and names the span open around it on
+    its own thread as `parent`; a span on another thread, or with
+    nothing open, has none."""
+    tr = Tracer()
+    seen = {}
+
+    def other():
+        with tr.span("worker", cat="t"):
+            pass
+
+    with tr.span("outer", cat="t"):
+        outer_id = tr.current_span()
+        with tr.span("inner", cat="t"):
+            inner_id = tr.current_span()
+            t = threading.Thread(target=other)
+            t.start()
+            t.join()
+        with tr.span("sibling", cat="t"):
+            pass
+    assert tr.current_span() is None
+    for name, _, _, _, args in tr.snapshot():
+        seen[name] = args
+    assert seen["outer"]["id"] == outer_id and "parent" not in seen["outer"]
+    assert seen["inner"]["id"] == inner_id
+    assert seen["inner"]["parent"] == outer_id
+    assert seen["sibling"]["parent"] == outer_id
+    assert "parent" not in seen["worker"]
+    ids = [a["id"] for a in seen.values()]
+    assert len(set(ids)) == len(ids)
+    assert tr.open_spans() == 0
+
+
+def test_current_span_is_none_when_disabled():
+    prev = obs_tracer._TRACER
+    obs_tracer._TRACER = None
+    try:
+        assert obs.current_span() is None
+    finally:
+        obs_tracer._TRACER = prev
+
+
+def test_spool_jobs_name_the_hook_span_that_caused_them():
+    """A store job carries the id of the `hook.offload` that enqueued it
+    into the worker's `spool.store` span as `cause`, with the time it
+    queued; a backward prefetch's `spool.load` names its `hook.fetch`."""
+    arrs = [[np.full((64,), st, np.float32)] for st in range(2)]
+    with _tracer_installed() as tr:
+        spool = ActivationSpool(HostMemoryBackend(),
+                                min_offload_elements=4,
+                                store_threads=1, load_threads=1)
+        bridge = HookBridge(spool)
+        for st in range(2):
+            bridge.offload(0, st, arrs[st])
+        spool.wait_io()                # stores land: fetches must load
+        for st in (1, 0):
+            np.testing.assert_array_equal(bridge.fetch(0, st)[0],
+                                          arrs[st][0])
+        spool.wait_io()
+        spool.close()
+    events = [ev for ev in tr.snapshot() if ev[3] >= 0]
+    by_id = {ev[4]["id"]: ev for ev in events}
+    stores = [ev for ev in events if ev[0] == "spool.store"]
+    loads = [ev for ev in events if ev[0] == "spool.load"]
+    assert len(stores) == 2 and len(loads) == 2
+    for ev in stores:
+        cause = by_id[ev[4]["cause"]]
+        assert cause[0] == "hook.offload"
+        assert ev[4]["queued_ms"] >= 0.0
+        # the worker started after the enqueuing span did
+        assert ev[2] >= cause[2]
+    stages = sorted(by_id[ev[4]["cause"]][4]["stage"] for ev in loads)
+    assert all(by_id[ev[4]["cause"]][0] == "hook.fetch" for ev in loads)
+    # fetch(stage 1) prefetches stage 0 and loads its own record on
+    # demand; fetch(stage 0) then waits on the prefetched load
+    assert stages == [1, 1]
+    # the copy is its own span inside each offload
+    copies = [ev for ev in events if ev[0] == "hook.copy"]
+    assert len(copies) == 2
+    for ev in copies:
+        assert by_id[ev[4]["parent"]][0] == "hook.offload"
+        assert ev[4]["bytes"] == 256
+
+
+def test_gauge_samples_are_a_counter_track_that_validates(tmp_path):
+    """A gauge is a timestamped sample on the ring, exported as a "C"
+    event per sample, so a queue depth reads as a track; the counter
+    table keeps the latest value."""
+    t = [0]
+
+    def clock():
+        t[0] += MS
+        return t[0]
+
+    tr = Tracer(clock=clock)
+    for depth in (1, 3, 2):
+        tr.set_gauge("spool.store_backlog", depth)
+    assert tr.counters()["spool.store_backlog"] == 2
+    samples = [ev for ev in tr.snapshot() if ev[3] == obs_tracer.GAUGE]
+    assert [ev[4]["value"] for ev in samples] == [1, 3, 2]
+    track = [ev for ev in obs_export.trace_events(tr)
+             if ev["ph"] == "C" and ev["name"] == "spool.store_backlog"]
+    assert [ev["args"]["value"] for ev in track] == [1, 3, 2]
+    assert [ev["ts"] for ev in track] == sorted(ev["ts"] for ev in track)
+    assert track[0]["ts"] > 0
+    path = str(tmp_path / "g.json")
+    obs_export.write_chrome_trace(path, tr)
+    assert obs_export.validate_trace(path) == []
+    # the analyzer reads spans only: samples do not count as I/O
+    assert obs_overlap.analyze(tr.snapshot())["io_busy_s"] == 0.0
+    bad = {"traceEvents": [{"name": "g", "ph": "C", "pid": 0, "tid": 0,
+                            "ts": 1.0, "args": {"value": "x"}}]}
+    assert obs_export.validate_trace(bad)
+
+
 # --------------------------------------------- concurrency / integrity
 
 def test_drop_counting_exact_under_threads():

@@ -153,6 +153,66 @@ def test_jit_metrics_keep_engine_aux_fields():
         assert rec["engine"] == "jit"
 
 
+def test_jit_step_spans_split_dispatch_from_wait(tmp_path):
+    """A traced jit step is `engine.dispatch` and then `engine.wait`,
+    with no span around both; the batch is drawn in `loader.next` and
+    the report built in `session.report`. `dispatch_time` is always on
+    and lies inside `step_time`."""
+    from repro import obs
+    with _session("jit", trace=str(tmp_path / "t.json")) as sess:
+        result = sess.run(3)
+        snap = obs.get_tracer().snapshot()
+    events = [ev for ev in snap if ev[3] >= 0]
+    # with tracing on, each backend compile is also an instant
+    assert any(ev[0] == "jax.compile" and ev[3] == obs.tracer.INSTANT
+               and ev[4]["fun"] for ev in snap)
+
+    def named(name):
+        return [ev for ev in events if ev[0] == name]
+
+    assert named("engine.step") == []
+    order = [(ev[0], ev[4]["step"]) for ev in events if ev[0] in (
+        "loader.next", "engine.dispatch", "engine.wait", "session.report")]
+    assert order == [(name, s) for s in range(3) for name in (
+        "loader.next", "engine.dispatch", "engine.wait", "session.report")]
+    assert all("parent" not in ev[4] for ev in events
+               if ev[0].startswith(("engine.", "loader.", "session.")))
+    # each dispatch ends before its wait begins
+    for d, w in zip(named("engine.dispatch"), named("engine.wait")):
+        assert d[2] + d[3] <= w[2]
+    for rep in result.reports:
+        assert 0.0 < rep.dispatch_time < rep.step_time
+        assert rep.to_metrics()["dispatch_time_s"] == rep.dispatch_time
+
+
+def test_compiles_are_counted_per_step_and_the_listener_leaves():
+    """The session's backend-compile counter: the first step compiles
+    its program, the next ones nothing; a compile in an on_report
+    callback is charged to no step; after close a compile event no
+    longer reaches the counter."""
+    import jax
+    import jax.numpy as jnp
+    from repro.obs.compiles import BACKEND_COMPILE_EVENT
+
+    def report_compiles(rep):
+        # a fresh program each step, compiled inside the callback
+        jax.jit(lambda x, k=rep.step: x * k)(jnp.ones(3)).block_until_ready()
+
+    sess = _session("jit")
+    with sess:
+        reports = sess.run(3, on_report=report_compiles).reports
+        assert [r.compiles for r in reports] == [1, 0, 0]
+        assert reports[0].to_metrics()["compiles"] == 1
+        counter = sess._compiles
+        before = counter.count
+        jax.monitoring.record_event_duration_secs(
+            BACKEND_COMPILE_EVENT, 0.1, fun_name="probe")
+        assert counter.count == before + 1
+    jax.monitoring.record_event_duration_secs(
+        BACKEND_COMPILE_EVENT, 0.1, fun_name="probe")
+    assert counter.count == before + 1
+
+
 # ------------------------------------------------------------- policies
 
 def test_policy_resolution_matrix():

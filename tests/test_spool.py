@@ -274,6 +274,20 @@ def test_bandwidth_limit_enforced():
     spool.close()
 
 
+def test_write_time_leaves_the_bandwidth_cap_out():
+    """`write_time` is the time inside the backend's write: the cap's
+    sleep counts in `store_time` only, so the write rate stays the
+    backend's."""
+    spool, _ = _spool(bandwidth_limit=2e6, store_threads=1)
+    spool.offload("k", [jnp.ones((512, 512), jnp.float32)])   # 1 MB
+    spool.wait_io()
+    st = spool.stats
+    assert st.store_time >= st.bytes_offloaded / 2e6 - 1e-3
+    assert 0.0 < st.write_time < 0.5 * st.store_time
+    assert st.snapshot().sub(st).write_time == 0.0
+    spool.close()
+
+
 # ------------------------------------------- data-plane stat regressions
 
 
