@@ -36,9 +36,10 @@ MIN_OFFLOAD_ELEMENTS = 2 ** 20
 
 def _block_residual_specs(cfg: ModelConfig, batch: int, seq: int,
                           settings: Optional[RunSettings] = None):
-    # Count under FlashAttention semantics (attn saves only q, k, v — the
-    # kernels' custom_vjp) to match the paper's FA-2 substrate (§4.1):
-    # the XLA chunked path would additionally count its per-chunk score
+    # Count under FlashAttention semantics (attn saves q, k, v and, where
+    # the fused causal pair covers the shapes, out and lse — the kernels'
+    # custom_vjp) to match the paper's FA-2 substrate (§4.1): the XLA
+    # chunked path would additionally count its per-chunk score
     # residuals, which FA never materialises.
     settings = settings or RunSettings(attn_impl="pallas_interpret",
                                        attn_chunk=1024,

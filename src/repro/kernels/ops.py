@@ -1,11 +1,11 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Forward runs the fused Pallas kernel; backward is a custom_vjp against the
-mathematically identical pure-JAX formulation (recompute-based, the same
-residual policy FlashAttention-2 uses: save nothing but inputs, rebuild the
-tiles in the backward pass). On TPU the backward would be its own kernel
-pair (dq and dkv sweeps); the recompute-vjp here is bit-compatible with
-that and keeps the oracle authoritative for gradients.
+`causal_attention` is the training path's attention on TPU: the splash
+kernels that ship with JAX (forward, dq, dk/dv), whose custom_vjp keeps
+(q, k, v, out, lse) and rebuilds each tile in VMEM, skipping fully masked
+causal blocks. The other wrappers run a Pallas forward and differentiate
+a pure-JAX formulation in backward: `flash_attention` the blockwise
+chunked path (no (Sq, Skv) scores), the scans their oracles.
 """
 from __future__ import annotations
 
@@ -37,10 +37,12 @@ def _fa_fwd(q, k, v, causal, window, logit_cap, interpret):
 
 
 def _fa_bwd(causal, window, logit_cap, interpret, res, g):
+    from repro.models.attention import attend_chunked
     q, k, v = res
     _, vjp = jax.vjp(
-        lambda q, k, v: ref.attention_reference(
-            q, k, v, causal=causal, window=window, logit_cap=logit_cap),
+        lambda q, k, v: attend_chunked(
+            q, k, v, causal=causal, window=window, logit_cap=logit_cap,
+            chunk=128),
         q, k, v)
     return vjp(g)
 
@@ -51,6 +53,41 @@ _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     logit_cap: float = 0.0, interpret: bool = False):
     return _flash_attention(q, k, v, causal, window, logit_cap, interpret)
+
+
+@functools.lru_cache(maxsize=32)
+def _splash(seq: int, heads: int, block: int, interpret: bool):
+    """The splash kernel for causal attention over `heads` query heads
+    (any number of kv heads that divides them). Its mask tables are
+    built eagerly, so a cached kernel holds no tracer of the trace that
+    first asked for it."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    b = block
+    sizes = sk.BlockSizes(block_q=b, block_kv=b, block_kv_compute=b,
+                          block_q_dkv=b, block_kv_dkv=b,
+                          block_kv_dkv_compute=b, block_q_dq=b,
+                          block_kv_dq=b)
+    mask = sm.MultiHeadMask([sm.CausalMask((seq, seq))] * heads)
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mha(mask, block_sizes=sizes, head_shards=1,
+                                  q_seq_shards=1, interpret=interpret)
+
+
+def causal_attention(q, k, v, *, block: int, interpret: bool = False):
+    """Causal self-attention through the fused splash pair.
+
+    q: (B, S, Hq, D); k, v: (B, S, Hkv, D); S % block == 0. Returns
+    (B, S, Hq, D) in q.dtype. The MXU takes q, k, v in their own dtype
+    with f32 accumulation; softmax statistics stay f32. q is scaled by
+    D**-0.5 in f32 and rounded once to its dtype.
+    """
+    B, S, Hq, D = q.shape
+    kernel = _splash(S, Hq, block, interpret)
+    qs = (q.astype(jnp.float32) * D ** -0.5).astype(q.dtype)
+    out = jax.vmap(kernel)(qs.swapaxes(1, 2), k.swapaxes(1, 2),
+                           v.swapaxes(1, 2))
+    return out.swapaxes(1, 2)
 
 
 # ------------------------------------------------------------ SSD scan

@@ -1,11 +1,13 @@
 """GQA attention: memory-efficient chunked online-softmax (XLA path),
 decode-step attention against full or ring KV caches, and dispatch to the
-Pallas flash kernel on TPU.
+fused Pallas pair for causal self-attention on TPU (`fused_block`).
 
-The chunked XLA path is mathematically identical to the Pallas kernel
-(kernels/flash_attention.py) and serves as its oracle; it never materialises
-an (Sq, Skv) score tensor larger than (Sq, chunk), which is what makes the
-32k/500k cells lowerable.
+The chunked XLA path is mathematically identical to the Pallas kernels
+and serves as their oracle, and as the path on other backends and shapes;
+it never materialises an (Sq, Skv) score tensor larger than (Sq, chunk),
+which is what makes the 32k/500k cells lowerable. Differentiated by JAX,
+though, its scan stacks every tile's f32 scores for backward; the fused
+pair keeps only (q, k, v, out, lse) and rebuilds the tiles in VMEM.
 
 Layout notes (measured on the 256-chip dry-run): KV heads are expanded to
 the query head count *inside* each chunk iteration, so every score/carry
@@ -198,11 +200,68 @@ def attend_decode(q, cache_k, cache_v, pos, *, window: int = 0,
     return out.reshape(B, 1, Hq, D).astype(q.dtype)
 
 
+FUSED_BLOCKS = (512, 256, 128)
+
+
+def fused_block(platform: str, *, sq: int, skv: int, head_dim: int,
+                causal: bool, window: int = 0, logit_cap: float = 0.0,
+                q_offset=0, kv_len=None, sharded: bool = False) -> int:
+    """Block size of the fused causal pair (`kernels.ops.causal_attention`)
+    for a call of these shapes on `platform`, or 0 where the call keeps
+    the XLA path.
+
+    The pair covers causal self-attention on one TPU: no window, no
+    softcap, no `kv_len`, `q_offset` 0 (training and full prefill), a
+    head size the MXU lanes tile and a sequence the block divides. GSPMD
+    cannot partition a `pallas_call`, so a call sharded over a mesh keeps
+    XLA. The block is the largest of `FUSED_BLOCKS` that divides S.
+    """
+    if platform != "tpu" or sharded or not causal or window or logit_cap:
+        return 0
+    if kv_len is not None or not isinstance(q_offset, int) or q_offset:
+        return 0
+    if sq != skv or head_dim % 128:
+        return 0
+    return next((b for b in FUSED_BLOCKS if sq % b == 0), 0)
+
+
+def gspmd_partitioned(mesh) -> bool:
+    """Whether GSPMD partitions a call traced here over more than one
+    device: an axis of the context mesh that is not manual has size > 1,
+    or, outside any mesh context, `mesh` spans several devices. Inside a
+    fully manual `shard_map` body every op is per device."""
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
+        return mesh is not None and mesh.size > 1
+    return any(t != jax.sharding.AxisType.Manual and am.shape[n] > 1
+               for n, t in zip(am.axis_names, am.axis_types))
+
+
 def attend(q, k, v, *, causal: bool, window: int = 0, logit_cap: float = 0.0,
            q_offset=0, kv_len=None, chunk: int = 1024, impl: str = "xla",
            settings: Any = None):
-    """Dispatcher: xla (chunked scan, blocked for causal/window) |
-    pallas | pallas_interpret."""
+    """Dispatcher. Where `fused_block` admits the call on this backend,
+    every impl runs the fused causal pair; otherwise `impl` chooses:
+    xla (chunked scan, blocked for causal/window) | pallas |
+    pallas_interpret (the forward kernel, a blockwise XLA backward).
+
+    So on TPU `impl="xla"` takes the fused pair wherever it applies.
+    "pallas_interpret" routes as on a TPU and interprets the kernels
+    on the host.
+    """
+    if impl not in ("xla", "pallas", "pallas_interpret"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    interpret = impl == "pallas_interpret"
+    blk = fused_block(
+        "tpu" if interpret else jax.default_backend(),
+        sq=q.shape[1], skv=k.shape[1], head_dim=q.shape[-1],
+        causal=causal, window=window, logit_cap=logit_cap,
+        q_offset=q_offset, kv_len=kv_len,
+        sharded=gspmd_partitioned(getattr(settings, "mesh", None)))
+    if blk:
+        from repro.kernels import ops as kops
+        return kops.causal_attention(q, k, v, block=blk,
+                                     interpret=interpret)
     if impl == "xla":
         import os
         Sq, Skv = q.shape[1], k.shape[1]
@@ -216,9 +275,7 @@ def attend(q, k, v, *, causal: bool, window: int = 0, logit_cap: float = 0.0,
         return attend_chunked(q, k, v, causal=causal, window=window,
                               logit_cap=logit_cap, q_offset=q_offset,
                               kv_len=kv_len, chunk=chunk, settings=settings)
-    if impl in ("pallas", "pallas_interpret"):
-        from repro.kernels import ops as kops
-        return kops.flash_attention(
-            q, k, v, causal=causal, window=window, logit_cap=logit_cap,
-            interpret=(impl == "pallas_interpret"))
-    raise ValueError(f"unknown attention impl {impl!r}")
+    from repro.kernels import ops as kops
+    return kops.flash_attention(
+        q, k, v, causal=causal, window=window, logit_cap=logit_cap,
+        interpret=interpret)

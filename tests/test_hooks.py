@@ -588,3 +588,27 @@ def test_backward_prefetch_covers_stage0(monkeypatch):
                     "path cannot be exercised under this load")
     # the buggy path pays one extra cold load on the critical path
     assert buggy_wait - fixed_wait > 0.5 * delay, (buggy_wait, fixed_wait)
+
+
+def test_fused_attention_spools_fewer_bytes():
+    """A spooled layer's residuals are the leaves of its vjp closure:
+    through the fused causal pair (interpreted here) attention leaves
+    (q, k, v, out, lse) there, through the XLA path the stacked f32
+    tiles of its scan. One bf16 GQA layer at 1 x 256 tokens."""
+    from repro.configs.paper_models import gpt
+    cfg = dataclasses.replace(gpt(256, 1, vocab=512), num_kv_heads=1)
+    spooled = {}
+    for impl in ("xla", "pallas_interpret"):
+        settings = RunSettings(attn_impl=impl, attn_chunk=128,
+                               activation_policy="spool")
+        with TrainSession(cfg, engine="jit", optimizer="adamw",
+                          batch_size=1, seq_len=256, seed=3,
+                          ckpt_every=0, settings=settings,
+                          min_offload_elements=MIN_OFF,
+                          io=SpoolIoConfig(
+                              backend="mem",
+                              host_offload="activations")) as sess:
+            result = sess.run(1)
+            assert np.isfinite(result.losses).all()
+            spooled[impl] = sess.spool.stats.bytes_offloaded
+    assert 0 < spooled["pallas_interpret"] < spooled["xla"]

@@ -2,13 +2,17 @@
 the pure-jnp oracles (interpret mode executes kernel bodies on CPU).
 Gradients flow through the custom_vjp wrappers and are checked against
 direct autodiff of the oracle."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.models.attention import attend_chunked
+from repro.models.attention import attend_chunked, fused_block
 from repro.models.mamba2 import ssd_chunked
 from repro.models.rglru import rglru_scan_xla
 
@@ -90,6 +94,98 @@ def test_flash_attention_grads():
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------- fused causal pair (splash kernels)
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("Hq,Hkv", [(4, 2), (8, 1)])
+@pytest.mark.parametrize("S", [256, 512])
+def test_causal_pair_matches_chunked_vjp(S, Hq, Hkv):
+    """Forward and (dq, dk, dv) of the fused pair against jax.vjp of the
+    chunked XLA path, bf16 GQA; block 128 so that fully masked blocks
+    are skipped and the diagonal blocks masked."""
+    q = _rand((1, S, Hq, 128), jnp.bfloat16)
+    k = _rand((1, S, Hkv, 128), jnp.bfloat16)
+    v = _rand((1, S, Hkv, 128), jnp.bfloat16)
+    g = _rand((1, S, Hq, 128), jnp.bfloat16)
+    out, vjp = jax.vjp(lambda *a: ops.causal_attention(
+        *a, block=128, interpret=True), q, k, v)
+    want, vjp_ref = jax.vjp(lambda *a: attend_chunked(
+        *a, causal=True, chunk=128), q, k, v)
+    assert out.dtype == jnp.bfloat16
+    for name, a, b in zip(("out", "dq", "dk", "dv"),
+                          (out, *vjp(g)), (want, *vjp_ref(g))):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert _rel(a, b) < 1e-2, name
+
+
+_CELL = dict(head_dim=128, causal=True)
+
+ROUTES = [
+    # (platform, shapes and options, fused block or 0 = XLA)
+    ("tpu", dict(sq=4096, skv=4096, **_CELL), 512),      # remat cell
+    ("tpu", dict(sq=1024, skv=1024, **_CELL), 512),      # spool cell
+    ("tpu", dict(sq=384, skv=384, **_CELL), 128),
+    ("tpu", dict(sq=4096, skv=4096, window=1024, **_CELL), 0),
+    ("tpu", dict(sq=4096, skv=4096, logit_cap=50.0, **_CELL), 0),
+    ("tpu", dict(sq=4096, skv=4096, kv_len=4000, **_CELL), 0),
+    ("tpu", dict(sq=512, skv=4096, q_offset=3584, **_CELL), 0),
+    ("tpu", dict(sq=1, skv=4096, q_offset=4095, **_CELL), 0),   # decode
+    ("tpu", dict(sq=4096, skv=4096, head_dim=64, causal=True), 0),
+    ("tpu", dict(sq=4096, skv=4096, head_dim=128, causal=False), 0),
+    ("tpu", dict(sq=4096, skv=4096, sharded=True, **_CELL), 0),
+    ("tpu", dict(sq=200, skv=200, **_CELL), 0),          # no block divides
+    ("cpu", dict(sq=4096, skv=4096, **_CELL), 0),
+    ("gpu", dict(sq=1024, skv=1024, **_CELL), 0),
+]
+
+
+@pytest.mark.parametrize("platform,kw,block", ROUTES)
+def test_fused_pair_routing(platform, kw, block):
+    assert fused_block(platform, **kw) == block
+
+
+PARTITIONED = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+from repro.models.attention import gspmd_partitioned
+
+mesh = jax.make_mesh((2, 2), ("a", "b"))
+seen = {"none": gspmd_partitioned(None), "mesh": gspmd_partitioned(mesh)}
+
+def body(key):
+    def f(x):
+        seen[key] = gspmd_partitioned(mesh)
+        return x
+    return f
+
+x = jnp.ones((4, 4))
+jax.jit(jax.shard_map(body("manual"), mesh=mesh, in_specs=P("a"),
+                      out_specs=P("a")))(x)
+jax.jit(jax.shard_map(body("partial"), mesh=mesh, in_specs=P("a"),
+                      out_specs=P("a"), axis_names={"a"}))(x)
+assert seen == {"none": False, "mesh": True, "manual": False,
+                "partial": True}, seen
+print("OK")
+"""
+
+
+def test_gspmd_partitioned_sees_manual_bodies():
+    """The fused pair's `sharded` input: a multi-device mesh partitions
+    the call unless it is traced inside a fully manual shard_map body
+    (4 forced host devices, in a subprocess)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    r = subprocess.run([sys.executable, "-c", PARTITIONED], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0 and "OK" in r.stdout, r.stderr[-2000:]
 
 
 # ------------------------------------------------------------ SSD scan

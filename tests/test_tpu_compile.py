@@ -16,6 +16,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.ops import causal_attention
+from repro.models.attention import fused_block
 from repro.kernels.rglru_scan import rglru_scan_fwd
 from repro.kernels.ssd_scan import ssd_scan_fwd
 
@@ -59,6 +61,27 @@ def test_flash_attention_compiles(one_chip, B, S, Hq, Hkv, D):
     kv = _sds(one_chip, (B, S, Hkv, D), jnp.bfloat16)
     compiled = flash_attention_fwd.lower(q, kv, kv, causal=True).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("S", [4096, 1024])
+def test_causal_pair_fwd_bwd_compiles(one_chip, S):
+    """Forward and backward of the fused pair (forward with residuals,
+    dq and dk/dv kernels) at qwen2.5-3b's widths, 16/2 heads x 128."""
+    B, Hq, Hkv, D = 1, 16, 2, 128
+    block = fused_block("tpu", sq=S, skv=S, head_dim=D, causal=True)
+    assert block
+
+    def fwd_bwd(q, k, v, g):
+        out, vjp = jax.vjp(lambda *a: causal_attention(*a, block=block),
+                           q, k, v)
+        return out, vjp(g)
+
+    q = _sds(one_chip, (B, S, Hq, D), jnp.bfloat16)
+    kv = _sds(one_chip, (B, S, Hkv, D), jnp.bfloat16)
+    compiled = jax.jit(fwd_bwd).lower(q, kv, kv, q).compile()
+    text = compiled.as_text()
+    for kernel in ("fwd_residuals", "dq", "dkv"):
+        assert f"splash_mha_{kernel}" in text, kernel
 
 
 def test_ssd_scan_compiles(one_chip):
