@@ -2,10 +2,12 @@
 
 Everything a cell is made of is found by name: the workload file
 `bench/workloads/<cell>.json`, the configuration file it names
-`bench/configs/<config>.json`, and one reader per metric
+`bench/configs/<config>.json`, the module of the family that file
+names `bench/families/<family>.py` (its mapping to the program, its
+FLOPs, its layout and its plain blocks), and one reader per metric
 `bench/metrics/<metric>.py`, which `BENCHMARK.json` lists for the cell.
-Adding a cell, a configuration or a metric adds files; no code here
-names one.
+Adding a cell, a configuration, a family or a metric adds files; no
+code here names one.
 
 A run, in order:
   set-up    weights and optimizer state made on the device from the
@@ -37,8 +39,6 @@ import time
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional
-
-import numpy as np
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -80,13 +80,30 @@ def load_config(name: str) -> Dict[str, Any]:
         return json.load(f)
 
 
-def metric_reader(name: str) -> Callable[["RunRecord"], Optional[float]]:
-    path = _by_name("metrics", name, ".py")
+def _module(kind: str, name: str):
+    path = _by_name(kind, name, ".py")
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str) -> Callable[["RunRecord"], Optional[float]]:
+    return _module("metrics", name).read
+
+
+def family(conf: Dict[str, Any]):
+    """The module `bench/families/<family>.py` that the configuration's
+    `family` key names: its mapping to the program, its FLOPs, its
+    layout and its plain blocks (bench/families/dense.py lists them)."""
+    name = conf["family"]
+    try:
+        return _module("families", name)
+    except FileNotFoundError:
+        found = sorted(p.stem for p in (BENCH / "families").glob("*.py"))
+        raise ValueError(f"configuration {conf.get('name')!r} names family "
+                         f"{name!r}; bench/families has {found}") from None
 
 
 def cell_metrics(bench: Dict[str, Any], cell: str, trace: bool) \
@@ -108,24 +125,6 @@ def load_peaks(kind: str) -> Dict[str, float]:
 
 
 # ------------------------------------------------------- configuration
-
-def model_config(conf: Dict[str, Any]):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig
-    if conf["family"] != "dense":
-        raise ValueError(f"family {conf['family']!r} has no mapping here")
-    return ModelConfig(
-        name=conf["name"], family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["head_dim"], d_ff=conf["intermediate_size"],
-        vocab_size=conf["vocab_size"], qkv_bias=conf["attention_bias"],
-        rope_theta=conf["rope_theta"], act=conf["hidden_act"],
-        norm_eps=conf["rms_norm_eps"],
-        tie_embeddings=conf["tie_word_embeddings"],
-        dtype=conf["torch_dtype"]).validate()
-
 
 def optimizer(opt: Dict[str, Any]):
     from repro.optim.optimizers import adamw
@@ -220,6 +219,17 @@ class RunRecord:
     spool_bytes: Optional[int] = None       # window total, spool cells
     trace: Optional[Dict[str, Any]] = None
     traced_steps: int = 0
+    # what the readers of the program's counters and spans read
+    # (bench/runstate.py): the window's StepReports in order (None where
+    # no harness run made the record), the program's repro.obs spans of
+    # the traced steps, the host clock of the trace's start mark, and
+    # the profiler's directory
+    reports: Optional[List[Any]] = None
+    spans: List[Any] = field(default_factory=list)
+    mark_ns: Optional[int] = None
+    prof_dir: Optional[str] = None
+    # what one reader worked out for the others (bench/runstate.py)
+    found: Dict[str, Any] = field(default_factory=dict)
 
 
 # ----------------------------------------------------------------- run
@@ -260,17 +270,17 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
     host-clock time the process started its set-up."""
     import jax
 
-    from bench import compare, flops, weights
+    from bench import compare, weights
     from bench import trace as trace_mod
     from bench.loader import UniformTokens, WindowLoader
-    from bench.reference.model import param_shapes
     from repro import obs
     from repro.runtime.trainer import TrainState
 
     bench = load_benchmark()
     wl = load_workload(cell)
     conf = load_config(wl["config"])
-    cfg = model_config(conf)
+    fam = family(conf)
+    cfg = fam.program_config(conf)
     opt_conf = wl["optimizer"]
     devices = jax.devices()[:wl["chips"]]
     dev = devices[0]
@@ -317,7 +327,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
     sess = None
     try:
         sess = build_session(wl, cfg, optimizer(opt_conf), loader, work)
-        shapes = param_shapes(conf)
+        shapes = fam.param_shapes(conf)
         prog_shapes = jax.eval_shape(sess.api.init, jax.random.key(0))
         if jax.tree.structure(shapes) != jax.tree.structure(prog_shapes) \
                 or jax.tree.leaves(shapes) != jax.tree.leaves(prog_shapes):
@@ -379,9 +389,9 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
                             tokens.batch(0, wl["batch"], wl["seq_len"])),
             ROOT / ".bench_cache" / "memory")
         trace_red = None
+        spans = traced["tracer"].snapshot() if traced["tracer"] else []
         if trace:
-            events = traced["tracer"].snapshot() if traced["tracer"] else []
-            trace_red = _reduce_trace(prof_dir, traced["mark"], events)
+            trace_red = _reduce_trace(prof_dir, traced["mark"], spans)
         prog = {"losses": [r.loss for r in probe.reports[:SETUP_STEPS]],
                 "grad_leaf_norms": probe.grad_norms,
                 "change_leaf_norms": probe.change_norms}
@@ -394,10 +404,11 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
             step_times=[r.step_time for r in win],
             step_ends=[t - loader.window_start
                        for t in probe.ends[SETUP_STEPS:]],
-            flops_per_token=flops.flops_per_token(conf, wl["seq_len"]),
+            flops_per_token=fam.flops_per_token(conf, wl["seq_len"]),
             peak=peak, peak_bytes_in_use=peak_in_use, compiled=compiled,
             spool_bytes=spool_bytes, trace=trace_red,
-            traced_steps=traced["steps"])
+            traced_steps=traced["steps"], reports=win, spans=spans,
+            mark_ns=traced["mark"], prof_dir=str(prof_dir))
         log(f"window: {len(win)} steps in {window_s:.3f}s, setup "
             f"{setup_s:.1f}s, peak_bytes_in_use {peak_in_use}, compiled "
             f"{compiled}")
@@ -409,7 +420,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
 
         # free the program's state before the reference runs
-        del state, win
+        del state, win, rec
         probe.reports = []
         sess.close()
         sess = None
@@ -473,14 +484,15 @@ def reference_readings(conf, wl, seed: int, tokens, *,
 
     from bench import compare, weights
     from bench.loader import setup_batches
-    from bench.reference.model import Reference, param_shapes, train_three
+    from bench.reference.model import Reference, train_three
 
-    params = weights.make_params(seed, param_shapes(conf), conf["init"])
+    fam = family(conf)
+    params = weights.make_params(seed, fam.param_shapes(conf), conf["init"])
     batches = setup_batches(tokens, SETUP_STEPS, wl["batch"],
                             wl["seq_len"])
     if batch_fn is not None:
         batches = [batch_fn(b) for b in batches]
-    ref = Reference(conf, quant=quant,
+    ref = Reference(conf, fam.blocks(conf), quant=quant,
                     residual_from=residual_from)
     out = train_three(ref, params, batches, wl["optimizer"],
                       compare.leaf_norms)

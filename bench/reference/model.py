@@ -1,13 +1,18 @@
-"""Plain float32 dense decoder and AdamW, computed layer by layer.
+"""Plain float32 decoder training and AdamW, computed layer by layer.
 
-Follows the published description of a Llama/Qwen2-style decoder:
-RMSNorm, grouped-query attention with rotary embeddings (rotate-half
-convention) and optional q/k/v bias, a SwiGLU MLP, a final RMSNorm and
-an output head, trained with token-mean cross-entropy and AdamW with
-global-norm clipping. It imports nothing of the program. It takes the
-weights as a tree in the program's layout, made by `bench.weights` from
-the seed, and computes everything in float32 under "highest" matmul
-precision. Where it departs from the published models, it follows the
+The part of the plain reference that every model family shares: the
+token embedding, a walk over the program's segments of blocks, a final
+RMSNorm and an output head, trained with token-mean cross-entropy and
+AdamW with global-norm clipping. The blocks themselves are a family's
+(`bench/families/<family>.py`, pieces in `bench/reference/decoder.py`):
+each is a function `block(x, p, c, quant, q_block)` of the activations
+x (B, S, D) f32 and one layer's f32 leaves, returning the new x, or
+(x, a scalar loss term) where the program adds one to its loss (an
+expert layer's balance and z-losses, with the program's coefficients).
+It imports nothing of the program. It takes the weights as a tree in
+the program's layout, made by `bench.weights` from the seed, and
+computes everything in float32 under "highest" matmul precision.
+Where it departs from the published models, it follows the
 configuration as run:
 
   * RMSNorm weights are stored as (weight - 1), so zeros are identity;
@@ -21,7 +26,9 @@ configuration as run:
 It runs in pieces so that it fits next to nothing else on one chip:
 one layer at a time (activations between layers are kept, each layer's
 inside is recomputed in backward), attention in blocks of queries, and
-the head and its cross-entropy in blocks of rows.
+the head and its cross-entropy in blocks of rows. A layer is one block
+of one repeat of a segment; layers are counted over all segments in
+the program's order.
 
 `quant="fp8"` computes every matmul, forward and backward, from
 float8_e4m3 operands with one scale per tensor: the control that a
@@ -31,8 +38,7 @@ layer l's backward gets the residuals (the saved input) of layer k.
 from __future__ import annotations
 
 import functools
-import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -91,62 +97,6 @@ def rms_norm(x, scale, eps: float):
     return x * r * (1.0 + scale)
 
 
-def rope(x, theta: float):
-    """x: (B, S, H, D); rotate-half rotary embedding at positions 0..S-1."""
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
-    ang = np.arange(x.shape[1], dtype=np.float64)[:, None] * freqs
-    cos = jnp.asarray(np.cos(ang), jnp.float32)[:, None, :]
-    sin = jnp.asarray(np.sin(ang), jnp.float32)[:, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def attention(q, k, v, quant, q_block: int):
-    """Causal softmax attention; q (B,S,H,D), k/v (B,S,Hkv,D). Query
-    blocks are recomputed in backward, so no (S, S) score tensor of the
-    whole sequence is kept."""
-    B, S, H, D = q.shape
-    G = H // k.shape[2]
-    k = jnp.repeat(k, G, axis=2)            # head h reads kv head h // G
-    v = jnp.repeat(v, G, axis=2)
-    qb = min(q_block, S)
-    nb = S // qb
-
-    @jax.checkpoint
-    def block(i, qi):
-        s = mm("bqhd,bkhd->bhqk", qi, k, quant) / math.sqrt(D)
-        rows = i * qb + jnp.arange(qb)[:, None]
-        s = jnp.where(jnp.arange(S)[None, :] <= rows, s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return mm("bhqk,bkhd->bqhd", p, v, quant)
-
-    qs = q.reshape(B, nb, qb, H, D).swapaxes(0, 1)
-    out = jax.lax.map(lambda a: block(*a), (jnp.arange(nb), qs))
-    return out.swapaxes(0, 1).reshape(B, S, H, D)
-
-
-def layer(x, p: Dict[str, Any], c: Dict[str, Any], quant, q_block: int):
-    """One decoder layer; x (B, S, D) f32, p the layer's f32 leaves."""
-    eps = c["rms_norm_eps"]
-    a = p["attn"]
-    h = rms_norm(x, p["norm"]["scale"], eps)
-    q = mm("bsd,dhk->bshk", h, a["wq"], quant)
-    k = mm("bsd,dhk->bshk", h, a["wk"], quant)
-    v = mm("bsd,dhk->bshk", h, a["wv"], quant)
-    if "bq" in a:
-        q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-    q = rope(q, c["rope_theta"])
-    k = rope(k, c["rope_theta"])
-    o = attention(q, k, v, quant, q_block)
-    x = x + mm("bshk,hkd->bsd", o, a["wo"], quant)
-    h = rms_norm(x, p["mlp_norm"]["scale"], eps)
-    m = p["mlp"]
-    g = mm("bsd,df->bsf", h, m["w_gate"], quant)
-    u = mm("bsd,df->bsf", h, m["w_in"], quant)
-    return x + mm("bsf,fd->bsd", jax.nn.silu(g) * u, m["w_out"], quant)
-
-
 def head_nll(x, fnorm, unembed, labels, c, quant):
     """Summed next-token NLL of rows x (R, D) against labels (R,); labels
     below 0 are not counted."""
@@ -164,26 +114,6 @@ def padded_vocab(c: Dict[str, Any]) -> int:
     return -(-c["vocab_size"] // 256) * 256
 
 
-def param_shapes(c: Dict[str, Any]):
-    """The weights' tree: the program's layout, from the configuration.
-    Decoder leaves are stacked over layers on their first axis."""
-    f = lambda *s: jax.ShapeDtypeStruct(s, jnp.dtype(c["torch_dtype"]))  # noqa: E731
-    L, D, F = c["num_hidden_layers"], c["hidden_size"], c["intermediate_size"]
-    H, KV, Q = (c["num_attention_heads"], c["num_key_value_heads"],
-                c["head_dim"])
-    V = padded_vocab(c)
-    attn = {"wq": f(L, D, H, Q), "wk": f(L, D, KV, Q), "wv": f(L, D, KV, Q),
-            "wo": f(L, H, Q, D)}
-    if c["attention_bias"]:
-        attn.update(bq=f(L, H, Q), bk=f(L, KV, Q), bv=f(L, KV, Q))
-    block = {"norm": {"scale": f(L, D)}, "attn": attn,
-             "mlp": {"w_in": f(L, D, F), "w_gate": f(L, D, F),
-                     "w_out": f(L, F, D)},
-             "mlp_norm": {"scale": f(L, D)}}
-    return {"final_norm": {"scale": f(D)}, "embed": f(V, D),
-            "unembed": f(D, V), "segments": [{"b0": block}]}
-
-
 def _f32(tree):
     return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
 
@@ -195,13 +125,17 @@ def _layer_at(stack, l):
 
 
 class Reference:
-    """Loss, gradients and AdamW steps of the plain decoder.
+    """Loss, gradients and AdamW steps of the plain model.
 
-    `c` is the configuration (published key names); attention runs in
-    blocks of `q_block` queries and the head in blocks of `head_rows`
-    rows (each at most the whole)."""
+    `c` is the configuration (published key names); `blocks` holds, for
+    each segment of the program's layout, the block function of each of
+    its blocks in the order b0, b1, ...; attention runs in blocks of
+    `q_block` queries and the head in blocks of `head_rows` rows (each
+    at most the whole)."""
 
-    def __init__(self, c: Dict[str, Any], quant: Optional[str] = None,
+    def __init__(self, c: Dict[str, Any],
+                 blocks: Sequence[Sequence[Callable]],
+                 quant: Optional[str] = None,
                  residual_from: Optional[Tuple[int, int]] = None,
                  q_block: int = Q_BLOCK, head_rows: int = HEAD_ROWS):
         self.c = dict(c)
@@ -211,20 +145,25 @@ class Reference:
         quant_ = quant
         cc = self.c
 
+        def jitted(block):
+            @jax.jit
+            def layer_fwd(x, stack, l):
+                return block(x, _layer_at(stack, l), cc, quant_, q_block)
+
+            @jax.jit
+            def layer_bwd(x, stack, l, g):
+                out, vjp = jax.vjp(
+                    lambda x_, p_: block(x_, p_, cc, quant_, q_block),
+                    x, _layer_at(stack, l))
+                if isinstance(out, tuple):    # d loss / d (x, loss term)
+                    return vjp((g, jnp.ones_like(out[1])))
+                return vjp(g)
+
+            return layer_fwd, layer_bwd
+
         @jax.jit
         def embed(table, tokens):
             return table[tokens].astype(jnp.float32)
-
-        @jax.jit
-        def layer_fwd(x, stack, l):
-            return layer(x, _layer_at(stack, l), cc, quant_, q_block)
-
-        @jax.jit
-        def layer_bwd(x, stack, l, g):
-            _, vjp = jax.vjp(lambda x_, p_: layer(x_, p_, cc, quant_,
-                                                  q_block),
-                             x, _layer_at(stack, l))
-            return vjp(g)
 
         @functools.partial(jax.jit, donate_argnums=(5,))
         def head_bwd(x, fnorm, unembed, labels, scale, d_unembed):
@@ -234,22 +173,42 @@ class Reference:
             dx, dn, du = vjp(scale)
             return nll, dx, dn, d_unembed + du
 
+        self._blocks = [[jitted(b) for b in seg] for seg in blocks]
         self._embed = embed
-        self._layer_fwd = layer_fwd
-        self._layer_bwd = layer_bwd
         self._head_bwd = head_bwd
 
+    def _layers(self, segments) -> List[Tuple[int, int, int]]:
+        """(segment, block, repeat) of every layer, in the program's
+        order: each repeat of a segment runs its blocks b0, b1, ..."""
+        if len(segments) != len(self._blocks) or any(
+                set(seg) != {f"b{i}" for i in range(len(fns))}
+                for seg, fns in zip(segments, self._blocks)):
+            raise ValueError(
+                f"the weights' segments {[sorted(s) for s in segments]} "
+                f"are not the family's blocks "
+                f"{[len(fns) for fns in self._blocks]}")
+        out = []
+        for s, seg in enumerate(segments):
+            n = jax.tree.leaves(seg)[0].shape[0]
+            out += [(s, i, r) for r in range(n) for i in range(len(seg))]
+        return out
+
     def loss_and_grads(self, params, batch):
-        """(mean loss, f32 gradients in the params' layout)."""
-        (seg,) = params["segments"]
-        (blk,) = seg.values()
-        L = jax.tree.leaves(blk)[0].shape[0]
+        """(mean loss, f32 gradients in the params' layout). The loss is
+        the token-mean cross-entropy plus the blocks' loss terms."""
+        segments = params["segments"]
+        layers = self._layers(segments)
         tokens = jnp.asarray(batch["tokens"])
         labels = np.asarray(batch["labels"])
         count = max(int(np.sum(labels >= 0)), 1)
         xs = [self._embed(params["embed"], tokens)]
-        for l in range(L):
-            xs.append(self._layer_fwd(xs[-1], blk, l))
+        terms = 0.0
+        for s, i, r in layers:
+            out = self._blocks[s][i][0](xs[-1], segments[s][f"b{i}"], r)
+            if isinstance(out, tuple):
+                out, term = out
+                terms += float(term)
+            xs.append(out)
         B, S, D = xs[-1].shape
         xf = xs[-1].reshape(B * S, D)
         lf = jnp.asarray(labels.reshape(B * S))
@@ -269,25 +228,27 @@ class Reference:
         del xf
         g = jnp.concatenate(dx_parts).reshape(B, S, D)
         del dx_parts
-        stacked = jax.tree.map(
-            lambda a: jnp.zeros(a.shape, jnp.float32), blk)
-        saved = {l: l for l in range(L)}
+        stacks = [{k: jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
+                                   v) for k, v in seg.items()}
+                  for seg in segments]
+        saved = list(range(len(layers)))
         if self.residual_from is not None:
             saved[self.residual_from[0]] = self.residual_from[1]
-        for l in reversed(range(L)):
-            g, dl = self._layer_bwd(xs[saved[l]], blk, l, g)
-            stacked = _set_layer(stacked, dl, l)
+        for n in reversed(range(len(layers))):
+            s, i, r = layers[n]
+            b = f"b{i}"
+            g, dl = self._blocks[s][i][1](xs[saved[n]], segments[s][b], r, g)
+            stacks[s][b] = _set_layer(stacks[s][b], dl, r)
         del xs
         d_embed = _embed_grad(params["embed"].shape, tokens, g)
         grads = {"embed": d_embed, "final_norm": {"scale": d_norm},
-                 "segments": [{next(iter(seg)): stacked}],
-                 "unembed": d_unembed}
+                 "segments": stacks, "unembed": d_unembed}
         if jax.tree.structure(grads) != jax.tree.structure(params):
-            raise ValueError("the reference handles an embedding, one "
-                             "stack of decoder layers, a final norm and "
-                             "a head; the weights hold "
+            raise ValueError("the reference handles an embedding, "
+                             "segments of stacked blocks, a final norm "
+                             "and a head; the weights hold "
                              f"{jax.tree.structure(params)}")
-        return nll / count, grads
+        return nll / count + terms, grads
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -50,6 +50,19 @@ def test_a_broken_step_is_not_correct(tiny_bench, monkeypatch, fault):
     assert res["correct"] is False, res["compared"]
 
 
+@pytest.mark.parametrize("fault", [unchanged, half_batch])
+def test_a_broken_step_of_a_two_segment_family_is_not_correct(
+        tiny_bench, monkeypatch, fault):
+    """The same faults in the test family with a dense segment and an
+    expert segment (bench/tests/data/families/moe.py)."""
+    from repro.session import session as session_mod
+    monkeypatch.setattr(session_mod, "make_host_train_step",
+                        broken(fault, session_mod.make_host_train_step))
+    res = harness.run_cell("tiny_moe.remat", 13, 0.5, False,
+                           time.perf_counter())
+    assert res["correct"] is False, res["compared"]
+
+
 def test_residuals_from_the_wrong_layer_are_not_correct(tiny_bench,
                                                         monkeypatch):
     from repro.core.hooks import HookBridge

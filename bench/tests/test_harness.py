@@ -49,13 +49,18 @@ def test_cell_metrics_follow_workloads_lists():
     names = lambda cell, trace: {m["name"] for m in  # noqa: E731
                                  harness.cell_metrics(bench, cell, trace)}
     spool, remat = "qwen2.5-3b-4l.spool-1x1024", "qwen2.5-3b-4l.remat-1x4096"
-    assert names(remat, False) == {"tokens_per_s", "mfu", "peak_hbm_gib",
-                                   "setup_s"}
+    keep = "qwen2.5-3b-4l.keep-1x1024"
+    for cell in (remat, keep):
+        assert names(cell, False) == {"tokens_per_s", "mfu", "peak_hbm_gib",
+                                      "setup_s"}
+        assert names(cell, True) == {
+            "host_gap_ms", "step_temp_gib", "device_idle_pct",
+            "device_busy_ms", "dispatch_ms", "window_compiles"}
     assert names(spool, False) == {"peak_hbm_gib", "setup_s"}
     assert names(spool, True) == {"step_temp_gib", "spool_gb"}
     assert "spool_gb" not in names(remat, True)
     # every per-layer metric moves an end-to-end metric of its cells
-    for cell in (spool, remat):
+    for cell in (spool, remat, keep):
         e2e = names(cell, False)
         for m in harness.cell_metrics(bench, cell, True):
             assert m["moves"] in e2e, (cell, m["name"])
@@ -171,14 +176,18 @@ def test_peak_hbm_takes_the_runtime_counter_when_it_is_larger():
 # ------------------------------------------------------- whole tiny runs
 
 @pytest.mark.parametrize("cell,trace", [("tiny.remat", False),
-                                        ("tiny.spool", True)])
+                                        ("tiny.spool", True),
+                                        ("tiny_window.remat", False),
+                                        ("tiny_moe.remat", False)])
 def test_tiny_cell_runs_end_to_end(tiny_bench, cell, trace):
+    """Each cell, the test families' (bench/tests/data/families/) too,
+    against its own reference."""
     res = harness.run_cell(cell, 2 ** 31 + 7, 1.0, trace,
                            time.perf_counter())
     json.dumps(res)
     assert res["correct"] is True, res["compared"]
     assert res["attempted"] >= 1
-    if cell == "tiny.remat":
+    if cell.endswith(".remat"):
         assert res["failed"] == 0
     # at these toy step times (~30 ms) the program's spool now and then
     # serves a record it never counted as stored, or fails a load and

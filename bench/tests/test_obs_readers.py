@@ -40,8 +40,15 @@ def report(i):
                                write_time=1.0 + i))
 
 
-def run_cell(rec, win, traced, prof_dir, name):
-    """Stands in for the harness's frame, whose locals the readers read."""
+def read_window(rec, win, traced, prof_dir, name):
+    """Reads `name` from `rec` carrying a window as the harness's run
+    hands it over: its reports, the tracer's spans, the start mark and
+    the profiler's directory."""
+    tracer = traced.get("tracer")
+    rec.reports = win
+    rec.spans = tracer.snapshot() if tracer else []
+    rec.mark_ns = traced.get("mark")
+    rec.prof_dir = prof_dir
     return harness.metric_reader(name)(rec)
 
 
@@ -58,7 +65,7 @@ READ = {
 @pytest.mark.parametrize("name", sorted(READ))
 def test_readers_on_a_recorded_window(name):
     rec = recorded_run()
-    got = run_cell(rec, [report(i) for i in range(4)], {}, None, name)
+    got = read_window(rec, [report(i) for i in range(4)], {}, None, name)
     assert got == pytest.approx(READ[name], rel=1e-12)
 
 
@@ -70,31 +77,36 @@ def test_readers_find_nothing_outside_the_harness_or_on_older_reports(
     # a program whose reports carry none of the new fields
     old = [NS(shard_stats={"global": {"offloads": 1}},
               stats=NS(bytes_offloaded=1)) for _ in range(4)]
-    assert run_cell(recorded_run(), old, {}, None, name) is None
+    assert read_window(recorded_run(), old, {}, None, name) is None
 
 
 def test_readers_keep_nothing_the_harness_frees():
     """The harness frees the program's state before the reference runs;
-    a reader that looked at its frame must not keep that state alive."""
+    a reader must not keep that state alive, nor the record it read."""
     class State:
         pass
 
-    def run_cell(rec, win, traced, prof_dir):
+    def read_all(rec, win):
         state = State()
         kept = weakref.ref(state)
+        rec.reports = win
         for name in READ:
             harness.metric_reader(name)(rec)
         del state
         gc.collect()
         return kept()
 
-    assert run_cell(recorded_run(), [report(i) for i in range(4)], {},
-                    None) is None
+    rec = recorded_run()
+    assert read_all(rec, [report(i) for i in range(4)]) is None
+    read = weakref.ref(rec)
+    del rec
+    gc.collect()
+    assert read() is None
 
 
 def test_dispatch_reads_nothing_when_every_step_was_traced():
     rec = recorded_run(traced_steps=3)
-    assert run_cell(rec, [report(i) for i in range(4)], {}, None,
+    assert read_window(rec, [report(i) for i in range(4)], {}, None,
                     "dispatch_ms") is None
 
 
@@ -215,9 +227,9 @@ def test_device_clock_readers_divide_by_traced_steps(monkeypatch):
     tracer = NS(snapshot=spans)
     traced = {"tracer": tracer, "mark": MARK_NS}
     win = [report(i) for i in range(4)]
-    assert run_cell(rec, win, traced, "/prof", "hook_exposed_ms") == \
+    assert read_window(rec, win, traced, "/prof", "hook_exposed_ms") == \
         pytest.approx(1e3 * 450e-9 / 2)
-    assert run_cell(rec, win, traced, "/prof", "hostcb_xfer_ms") == \
+    assert read_window(rec, win, traced, "/prof", "hostcb_xfer_ms") == \
         pytest.approx(1e3 * 250e-9 / 2)
 
 
